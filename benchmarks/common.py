@@ -10,11 +10,20 @@ import time
 from typing import Any, Callable
 
 import jax
-
-# The paper's accuracy tiers (setup #3: <1e-14 eigenvalue error) require f64.
-jax.config.update("jax_enable_x64", True)
-
 import numpy as np
+
+from repro.launch.compile_cache import enable_compile_cache
+
+# The paper's accuracy tiers (setup #3: <1e-14 eigenvalue error) need
+# float64, which the CPU has.  A TPU has no float64, and its Pallas window
+# kernels take float32 only: on the chip every benchmark runs in float32.
+ON_TPU = jax.default_backend() == "tpu"
+jax.config.update("jax_enable_x64", not ON_TPU)
+enable_compile_cache()
+
+
+def precision() -> str:
+    return "float64" if jax.config.jax_enable_x64 else "float32"
 
 RESULTS_DIR = os.environ.get("REPRO_BENCH_DIR", "experiments/bench")
 
@@ -36,6 +45,10 @@ class Reporter:
     def __init__(self, name: str):
         self.name = name
         self.rows: list[Row] = []
+        dev = jax.devices()[0]
+        print(f"# {name}: platform={dev.platform} kind={dev.device_kind} "
+              f"count={jax.device_count()} precision={precision()}",
+              flush=True)
 
     def add(self, case: str, value: float, unit: str, **extra) -> None:
         row = Row(self.name, case, float(value), unit, extra)

@@ -202,7 +202,8 @@ class FastsumOperator:
         see :func:`repro.core.fastsum_exec.resolve_backend`).
         """
         if self.multiplier_half is None:  # legacy operators built by hand
-            fastsum_exec.resolve_backend(backend)  # validate even when unused
+            # validate even when unused
+            fastsum_exec.resolve_backend(backend, self.plan, 1, x.dtype)
             return self.matvec_tilde_reference(x)
         f = fastsum_exec.fused_matvec_tilde(
             self.plan, self.multiplier_half, self.src_window,
@@ -714,7 +715,8 @@ def direct_matvec_tiled(kernel: Kernel, points: Array, x: Array,
         row_ids = i * tile + jnp.arange(tile)
         col_ids = jnp.arange(n)
         w = jnp.where(row_ids[:, None] == col_ids[None, :], 0.0, w)
-        return w @ x
+        # a float32 reference: TPU's default float32 matmul is one bf16 pass
+        return jnp.matmul(w, x, precision=jax.lax.Precision.HIGHEST)
 
     out = jax.lax.map(row_block, jnp.arange(n_tiles))
     return out.reshape(-1, *x.shape[1:])[:n]

@@ -43,9 +43,10 @@ backends selected by ``backend="auto"|"xla"|"pallas"``:
   scatter-adds into / gathers from only the (taps,)^d patch it touches,
   with the weight tensor product and batched channels kept in-register.
 
-``backend="auto"`` (the default everywhere) picks pallas on TPU and xla
-elsewhere, so ``FastsumOperator.matvec``, block Lanczos, and the
-distributed matvec pick the fast path up transparently.
+``backend="auto"`` (the default everywhere) picks pallas on TPU when the
+resident grid fits VMEM and xla otherwise (:func:`resolve_backend`), so
+``FastsumOperator.matvec``, block Lanczos, and the distributed matvec pick
+the kernels up transparently.
 
 Everything is natively multi-RHS: ``x`` of shape (n,) or (n, C) flows
 through with a trailing channel dimension on the grid, so block Lanczos /
@@ -55,7 +56,6 @@ multi-column solves amortize spread and gather over the batch.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -64,68 +64,42 @@ import numpy as np
 from repro.core.nfft import (
     NfftPlan, WindowGeometry, _embed_map, padded_grid_size, window_shift,
 )
-from repro.kernels import nfft_window
+from repro.kernels import nfft_window, ops as kernel_ops
 
 Array = jax.Array
 
 BACKENDS = ("auto", "xla", "pallas")
 
 
-def resolve_backend(backend: str | None) -> str:
-    """Resolve the window-step backend: auto -> pallas on TPU, xla elsewhere.
+def resolve_backend(backend: str | None, plan: NfftPlan, channels: int,
+                    dtype) -> str:
+    """Resolve the window-step backend for one spread or gather.
+
+    ``"auto"`` picks the Pallas kernels on TPU when their inputs are
+    float32 and the resident padded grid of ``channels`` lanes fits VMEM
+    (:func:`repro.kernels.nfft_window.grid_fits_vmem`, the one threshold),
+    and the XLA path otherwise: off TPU, for float64 (Mosaic has no 64-bit
+    floats), and for grids too large to stay resident (d=3 at SETUP_2/3).
+    Everything it reads is static, so the choice is made once per traced
+    shape, and a kernel that then fails to compile raises — nothing falls
+    back behind the caller's back.
 
     An *explicit* ``"pallas"`` off-TPU runs the kernels in interpret mode —
     the per-node streaming loop executed by the Pallas emulator.  That is
     the parity-testing path (bit-identical semantics to the TPU lowering),
     not a performance path; benchmarks must not time it.
-
-    Caveat: the TPU Mosaic lowering of these kernels has not yet been
-    exercised on real hardware (ROADMAP follow-up) — on TPU, pass
-    ``backend="xla"`` to opt out of the auto-selected pallas path.
     """
     if backend is None or backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        if jax.default_backend() != "tpu" or \
+                jnp.dtype(dtype) != jnp.float32:
+            return "xla"
+        fits = nfft_window.grid_fits_vmem(padded_grid_size(plan), plan.d,
+                                          channels)
+        return "pallas" if fits else "xla"
     if backend not in ("xla", "pallas"):
         raise ValueError(
             f"backend must be one of {BACKENDS}, got {backend!r}")
     return backend
-
-
-def _pallas_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-# Sticky degradation state for the *auto-selected* pallas window backend:
-# if its lowering fails (e.g. an unexercised Mosaic path on new hardware),
-# fall back to the xla backend for the rest of the process with ONE warning
-# instead of raising on every matvec.  An *explicit* ``backend="pallas"``
-# still raises — asking for pallas by name means wanting the failure.
-_PALLAS_FALLBACK = {"warned": False, "disabled": False}
-
-
-def _auto_backend(backend: str | None) -> bool:
-    return backend is None or backend == "auto"
-
-
-def _note_pallas_fallback(exc: Exception) -> None:
-    _PALLAS_FALLBACK["disabled"] = True
-    if not _PALLAS_FALLBACK["warned"]:
-        _PALLAS_FALLBACK["warned"] = True
-        warnings.warn(
-            "auto-selected pallas window backend failed to lower "
-            f"({type(exc).__name__}: {exc}); degrading to the xla window "
-            "backend for the rest of the process (pass backend='pallas' "
-            "explicitly to make this an error)",
-            RuntimeWarning, stacklevel=4)
-
-
-def _window_backend(backend: str | None) -> str:
-    """:func:`resolve_backend` plus the sticky auto-fallback state."""
-    resolved = resolve_backend(backend)
-    if (resolved == "pallas" and _auto_backend(backend)
-            and _PALLAS_FALLBACK["disabled"]):
-        return "xla"
-    return resolved
 
 
 def fused_spectral_multiplier(plan: NfftPlan, b_hat: Array) -> Array:
@@ -350,17 +324,11 @@ def window_spread(plan: NfftPlan, geometry: WindowGeometry, x: Array, *,
     """
     d, grid, taps = plan.d, plan.grid_size, plan.taps
     pad_n = padded_grid_size(plan)
-    xs = x[geometry.perm]  # align node values with the Morton-sorted rows
-    if _window_backend(backend) == "pallas":
-        try:
-            gpad = nfft_window.window_spread(
-                xs, geometry.base, geometry.weights, padded_size=pad_n,
-                interpret=_pallas_interpret())
-        except Exception as exc:  # lowering failure surfaces at trace time
-            if not _auto_backend(backend):
-                raise
-            _note_pallas_fallback(exc)
-            gpad = _xla_spread(plan, geometry, xs)
+    # align node values with the Morton-sorted rows
+    xs = x if geometry.perm is None else x[geometry.perm]
+    if resolve_backend(backend, plan, xs.shape[-1], xs.dtype) == "pallas":
+        gpad = kernel_ops.window_spread(xs, geometry.base, geometry.weights,
+                                        padded_size=pad_n)
     else:
         gpad = _xla_spread(plan, geometry, xs)
     # fold the periodic pad back: unwrapped u and u - M are the same cell
@@ -385,18 +353,12 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
     d, taps = plan.d, plan.taps
     rolled = jnp.roll(g, (window_shift(plan),) * d, axis=tuple(range(d)))
     gpad = jnp.pad(rolled, [(0, taps - 1)] * d + [(0, 0)], mode="wrap")
-    if _window_backend(backend) == "pallas":
-        try:
-            out = nfft_window.window_gather(
-                gpad, geometry.base, geometry.weights,
-                interpret=_pallas_interpret())
-        except Exception as exc:  # lowering failure surfaces at trace time
-            if not _auto_backend(backend):
-                raise
-            _note_pallas_fallback(exc)
-            out = _xla_gather(plan, geometry, gpad)
+    if resolve_backend(backend, plan, g.shape[-1], g.dtype) == "pallas":
+        out = kernel_ops.window_gather(gpad, geometry.base, geometry.weights)
     else:
         out = _xla_gather(plan, geometry, gpad)
+    if geometry.perm is None:
+        return out
     # restore node order via the inverse permutation as a row *take*: the
     # equivalent multi-channel row scatter costs ~10x more on XLA CPU, and
     # the (n,) int scatter building the inverse is single-channel (cheap)

@@ -15,6 +15,11 @@ the ``k`` algebraically largest (or smallest) Ritz pairs.
 Everything is jit-compatible: the iteration is a ``lax.fori_loop`` over a
 preallocated basis, the matvec is an arbitrary traceable callable (dense,
 fast-summation, or Pallas-backed).
+
+Every inner product and basis product runs at float32 precision
+(``Precision.HIGHEST``): on TPU the default for a float32 matmul is one
+bfloat16 pass, whose ~4e-3 relative rounding would cap orthogonality and
+the Ritz pairs far above float32 accuracy.
 """
 
 from __future__ import annotations
@@ -27,6 +32,8 @@ import jax.numpy as jnp
 
 Array = jax.Array
 Matvec = Callable[[Array], Array]
+
+_mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
 class LanczosResult(NamedTuple):
@@ -89,14 +96,15 @@ def lanczos_machine(matvec: Matvec, v0: Array, num_iters: int,
         alive = i < breakdown
         qi = basis[i]
         w = matvec(qi)
-        alpha = jnp.vdot(qi, w).real.astype(dtype)
+        alpha = jnp.vdot(qi, w, precision=jax.lax.Precision.HIGHEST
+                         ).real.astype(dtype)
         w = w - alpha * qi - jnp.where(i > 0, betas[i], 0.0) * basis[jnp.maximum(i - 1, 0)]
         if reorthogonalize:
             # two-pass CGS against the filled part of the basis
             mask = (jnp.arange(num_iters) <= i)[:, None].astype(dtype)
             for _ in range(2):
-                coeffs = (basis * mask) @ w
-                w = w - ((basis * mask).T @ coeffs)
+                coeffs = _mm(basis * mask, w)
+                w = w - _mm((basis * mask).T, coeffs)
         beta = jnp.linalg.norm(w)
         # breakdown guard: a non-finite recurrence step (poisoned matvec)
         # truncates the factorization — nothing at/after it is ever
@@ -180,18 +188,18 @@ def block_lanczos_machine(matvec: Matvec, v0: Array, num_blocks: int,
         basis, a_blocks, b_blocks, resid, breakdown = carry
         qj = basis[j]
         w = matvec(qj)  # (n, b): one batched operator application
-        a = qj.T @ w
+        a = _mm(qj.T, w)
         a = 0.5 * (a + a.T)  # exact symmetry of the diagonal block
-        w = w - qj @ a
-        w = w - jnp.where(j > 0, 1.0, 0.0) * (
-            basis[jnp.maximum(j - 1, 0)] @ b_blocks[j].T)
+        w = w - _mm(qj, a)
+        w = w - jnp.where(j > 0, 1.0, 0.0) * _mm(
+            basis[jnp.maximum(j - 1, 0)], b_blocks[j].T)
         if reorthogonalize:
             # two-pass block CGS against the filled part of the basis
             mask = (jnp.arange(num_blocks) <= j)[:, None, None].astype(dtype)
             flat = jnp.moveaxis(basis * mask, 1, 0).reshape(n, num_blocks * b)
             for _ in range(2):
-                coeffs = flat.T @ w  # (blocks*b, b)
-                w = w - flat @ coeffs
+                coeffs = _mm(flat.T, w)  # (blocks*b, b)
+                w = w - _mm(flat, coeffs)
         q_next, r_next = jnp.linalg.qr(w)
         # breakdown guard: truncate the factorization at the first block
         # step with a non-finite recurrence (see ``lanczos``)
@@ -352,9 +360,9 @@ def ritz_from_block(res: BlockLanczosResult, setup: EigshSetup,
              else jnp.argsort(theta))[:k]
     theta_k = theta[order]
     w_k = w[:, order]
-    vecs = basis_flat @ w_k
+    vecs = _mm(basis_flat, w_k)
     bottom = w_k[-block_size:, :]  # (b, k) last-block Ritz components
-    bounds = jnp.linalg.norm(res.residual_block @ bottom, axis=0)
+    bounds = jnp.linalg.norm(_mm(res.residual_block, bottom), axis=0)
     bounds = jnp.where(broke, jnp.inf, bounds)
     return EigshResult(eigenvalues=theta_k, eigenvectors=vecs,
                        residual_bounds=bounds,
@@ -380,7 +388,7 @@ def ritz_from_lanczos(res: LanczosResult, setup: EigshSetup) -> EigshResult:
              else jnp.argsort(theta))[:k]
     theta_k = theta[order]
     w_k = w[:, order]
-    vecs = res.basis.T @ w_k  # (n, k)
+    vecs = _mm(res.basis.T, w_k)  # (n, k)
     bounds = jnp.abs(res.residual_beta * w_k[-1, :])
     bounds = jnp.where(broke, jnp.inf, bounds)
     return EigshResult(eigenvalues=theta_k, eigenvectors=vecs,

@@ -183,21 +183,26 @@ def morton_codes(cells: Array, grid_size: int, dtype=jnp.int32) -> Array:
     return code
 
 
-def _morton_perm(cells: Array, grid_size: int) -> Array:
-    """argsort by Morton code, falling back gracefully for huge grids.
+def _morton_keys(cells: Array, grid_size: int) -> Array | None:
+    """Morton codes of the cells, or None for grids too large to encode.
 
     Plans whose interleaved code would overflow int32 use int64 when x64 is
     enabled; otherwise sorting is skipped (identity order) — ordering is a
     layout optimization, never a semantic requirement.
     """
-    n, d = cells.shape
-    bits = max(1, int(grid_size - 1).bit_length())
-    if bits * d <= 30:
-        codes = morton_codes(cells, grid_size)
-    elif jax.config.jax_enable_x64 and bits * d <= 62:
-        codes = morton_codes(cells, grid_size, dtype=jnp.int64)
-    else:
-        return jnp.arange(n, dtype=jnp.int32)
+    bits = max(1, int(grid_size - 1).bit_length()) * cells.shape[1]
+    if bits <= 30:
+        return morton_codes(cells, grid_size)
+    if jax.config.jax_enable_x64 and bits <= 62:
+        return morton_codes(cells, grid_size, dtype=jnp.int64)
+    return None
+
+
+def _morton_perm(cells: Array, grid_size: int) -> Array:
+    """argsort by Morton code (identity order for huge grids)."""
+    codes = _morton_keys(cells, grid_size)
+    if codes is None:
+        return jnp.arange(cells.shape[0], dtype=jnp.int32)
     return jnp.argsort(codes).astype(jnp.int32)
 
 
@@ -264,6 +269,8 @@ class WindowGeometry:
       (the padded-grid coordinate system; see ``pad_width``).
     weights: (n, d, taps) — per-dimension window values.
     perm: (n,) int32 — rows are Morton-sorted; row ``r`` is node ``perm[r]``.
+      ``None`` when the rows already are in node order (the per-shard
+      geometry of the distributed matvec, whose caller pre-permutes).
     """
 
     base: Array
@@ -292,19 +299,38 @@ def padded_grid_size(plan: NfftPlan) -> int:
     return plan.grid_size + plan.taps - 1
 
 
+def _morton_sort(cells: Array, grid_size: int, nodes: Array):
+    """Nodes in Morton order of their cells: ``(perm, nodes[perm])``.
+
+    One stable multi-operand sort carries the node coordinates along with
+    the codes, so no row gather follows it: on a mesh, a gather by a
+    sharded permutation partitions into a masked local gather plus an
+    all-reduce, where the sort only all-gathers its (rows, d + 2) operands.
+    Same order as :func:`_morton_perm`.
+    """
+    iota = jnp.arange(cells.shape[0], dtype=jnp.int32)
+    codes = _morton_keys(cells, grid_size)
+    if codes is None:
+        return iota, nodes
+    out = jax.lax.sort((codes, iota) + tuple(nodes.T), num_keys=1,
+                       is_stable=True)
+    return out[1], jnp.stack(out[2:], axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("plan", "sort"))
 def build_window_geometry(plan: NfftPlan, nodes: Array, *,
                           sort: bool = True) -> WindowGeometry:
     """Separable (fused-engine) window geometry for nodes in [-1/2, 1/2)^d."""
     n, d = nodes.shape
     assert d == plan.d, (d, plan.d)
-    base, _, w_d = _window_taps_1d(plan, nodes)
-    base = base + window_shift(plan)  # into [0, grid_size)
+    perm = jnp.arange(n, dtype=jnp.int32)
     if sort:
-        perm = _morton_perm(base, plan.grid_size)
-    else:
-        perm = jnp.arange(n, dtype=jnp.int32)
-    return WindowGeometry(base=base[perm], weights=w_d[perm], perm=perm)
+        cells = (jnp.floor(nodes * plan.grid_size).astype(jnp.int32)
+                 - plan.m + window_shift(plan))  # the rows' base corners
+        perm, nodes = _morton_sort(cells, plan.grid_size, nodes)
+    base, _, w_d = _window_taps_1d(plan, nodes)
+    return WindowGeometry(base=base + window_shift(plan), weights=w_d,
+                          perm=perm)
 
 
 def _window_fourier_1d_np(plan: NfftPlan, k: np.ndarray) -> np.ndarray:

@@ -20,12 +20,8 @@ Modules
     Block-wise int8 quantization with error feedback for gradient
     all-reduce (``compress_psum``) and per-step compression in the train
     loop (``apply_error_feedback``).
-``compat``
-    ``shard_map`` import shim across jax versions (``check_rep`` vs
-    ``check_vma`` keyword, ``jax.experimental`` vs top-level export).
 """
 
-from repro.dist.compat import shard_map
 from repro.dist.compression import (
     BLOCK, CompressionState, apply_error_feedback, compress_decompress,
     compress_psum, init_compression_state)
@@ -43,5 +39,5 @@ __all__ = [
     "compress_decompress", "compress_psum", "distributed_matvec_fn",
     "init_compression_state", "make_pencil_spec", "make_sharded_matvec",
     "named", "param_specs", "pencil_irfftn", "pencil_rfftn",
-    "resolve_pencil_spec", "shard_map",
+    "resolve_pencil_spec",
 ]

@@ -42,12 +42,12 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import fastsum_exec, nfft as nfft_mod
 from repro.core.nfft import NfftGeometry, NfftPlan, WindowGeometry
 from repro.dist import pencil_fft
-from repro.dist.compat import shard_map
 
 Array = jax.Array
 
@@ -219,13 +219,13 @@ def make_sharded_matvec(plan: NfftPlan, mesh, axes, *,
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(), P(axes, None), P(axes, None, None),
                                  P(axes, None)),
-                       out_specs=P(axes, None), check_rep=False)
+                       out_specs=P(axes, None), check_vma=False)
     def _mv(mult_half, base_, w_, x_):
-        # rows are globally Morton-sorted; the caller pre-permutes x, so the
-        # per-shard geometry uses an identity perm over its local rows.
-        local = WindowGeometry(
-            base=base_, weights=w_,
-            perm=jnp.arange(base_.shape[0], dtype=jnp.int32))
+        # rows are globally Morton-sorted and the caller pre-permutes x, so
+        # the per-shard rows already are in order: no perm (no local take
+        # and no inverse-permutation scatter, which the TPU compiler cannot
+        # build inside shard_map)
+        local = WindowGeometry(base=base_, weights=w_, perm=None)
         if spec is not None:
             return _pencil_matvec_local(plan, mult_half, local, x_, spec,
                                         backend=backend)
@@ -269,11 +269,9 @@ def make_sharded_matvec_bank(plan: NfftPlan, mesh, axes, *,
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P(), P(axes, None), P(axes, None, None),
                                  x_spec),
-                       out_specs=P(None, axes, None), check_rep=False)
+                       out_specs=P(None, axes, None), check_vma=False)
     def _mv(mult_bank, base_, w_, x_):
-        local = WindowGeometry(
-            base=base_, weights=w_,
-            perm=jnp.arange(base_.shape[0], dtype=jnp.int32))
+        local = WindowGeometry(base=base_, weights=w_, perm=None)
         if spec is not None:
             return _pencil_matvec_bank_local(plan, mult_bank, local, x_,
                                              spec, backend=backend)
@@ -303,6 +301,18 @@ def _pad_ghost_geometry(win: WindowGeometry, n: int, nshard: int):
     return base, w1d, perm, inv_perm, pad
 
 
+def _unsort_rows(y_sorted: Array, inv_perm: Array, mesh, axis: int) -> Array:
+    """Row take back into node order, replicated over ``mesh``.
+
+    The take reads rows from every shard, so its output sharding cannot be
+    inferred from a node-sharded operand; naming it (replicated, like the
+    caller's ``x``) lets the same code run on meshes with Auto and with
+    Explicit axes (``jax.make_mesh``'s default).
+    """
+    idx = (slice(None),) * axis + (inv_perm,)
+    return y_sorted.at[idx].get(out_sharding=NamedSharding(mesh, P()))
+
+
 def distributed_matvec_fn(op, mesh, axes, *, backend: str | None = None,
                           spectral_mode: str = "psum", pencil_axes=None):
     """Sharded drop-in for ``op.matvec`` (op: :class:`FastsumOperator`).
@@ -311,8 +321,9 @@ def distributed_matvec_fn(op, mesh, axes, *, backend: str | None = None,
     (n,) or (n, C), with the node dimension sharded over ``axes`` of
     ``mesh``.  The node count is padded with zero-weight ghost nodes to a
     multiple of the shard count, so any (n, mesh) combination works.
-    ``backend`` selects the per-shard window-step backend (default "auto":
-    pallas on TPU, xla elsewhere); ``spectral_mode`` selects the cross-shard
+    ``backend`` selects the per-shard window-step backend (default "auto",
+    see :func:`repro.core.fastsum_exec.resolve_backend`); ``mesh`` may have
+    Auto or Explicit axes; ``spectral_mode`` selects the cross-shard
     spectral accumulation (see module docstring); ``pencil_axes`` optionally
     overrides the pencil row/col mesh-axis split.
     """
@@ -343,7 +354,7 @@ def distributed_matvec_fn(op, mesh, axes, *, backend: str | None = None,
         if pad:
             xp = jnp.pad(xp, ((0, pad), (0, 0)))
         y_sorted = _mv(op.multiplier_half, base, w1d, xp[perm])
-        y = y_sorted[inv_perm]
+        y = _unsort_rows(y_sorted, inv_perm, mesh, 0)
         if pad:
             y = y[:n]
         if not batched:
@@ -396,7 +407,7 @@ def distributed_matvec_bank_fn(bank, mesh, axes, *,
             xb = x if batched else x[:, None]
             xp = jnp.pad(xb, ((0, pad), (0, 0))) if pad else xb
             y_sorted = _mv_bcast(bank.multiplier_bank, base, w1d, xp[perm])
-        y = y_sorted[:, inv_perm]
+        y = _unsort_rows(y_sorted, inv_perm, mesh, 1)
         if pad:
             y = y[:, :n]
         if lockstep:

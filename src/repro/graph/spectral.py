@@ -54,7 +54,9 @@ def kmeans(key: Array, points: Array, k: int, num_iters: int = 50) -> KMeansResu
         assign = jnp.argmin(d2, axis=1)
         one_hot = jax.nn.one_hot(assign, k, dtype=points.dtype)
         counts = jnp.maximum(one_hot.sum(0), 1.0)
-        new_centers = (one_hot.T @ points) / counts[:, None]
+        new_centers = jnp.matmul(one_hot.T, points,
+                                 precision=jax.lax.Precision.HIGHEST
+                                 ) / counts[:, None]
         # keep empty clusters where they were
         new_centers = jnp.where((one_hot.sum(0) > 0)[:, None], new_centers, centers)
         return new_centers
@@ -70,6 +72,7 @@ class SpectralResult(NamedTuple):
     assignments: Array
     eigenvalues: Array
     eigenvectors: Array
+    residual_bounds: Array | None = None  # Lanczos bounds; None if given
 
 
 def spectral_clustering(adjacency: NormalizedAdjacencyOperator, k: int,
@@ -87,17 +90,20 @@ def spectral_clustering(adjacency: NormalizedAdjacencyOperator, k: int,
     # independent streams for the Lanczos start vector and the k-means++
     # init — reusing one key would correlate the two randomizations
     key_eigs, key_kmeans = jax.random.split(key)
+    bounds = None
     if eigenvectors is None:
         res = eigsh(adjacency.matvec, adjacency.n, k,
                     num_iters=num_lanczos_iters, key=key_eigs,
                     block_size=block_size,
                     dtype=adjacency.inv_sqrt_deg.dtype)
         eigenvectors, eigenvalues = res.eigenvectors, res.eigenvalues
+        bounds = res.residual_bounds
     rows = eigenvectors / jnp.maximum(
         jnp.linalg.norm(eigenvectors, axis=1, keepdims=True), 1e-30)
     km = kmeans(key_kmeans, rows, k)
     return SpectralResult(assignments=km.assignments,
-                          eigenvalues=eigenvalues, eigenvectors=eigenvectors)
+                          eigenvalues=eigenvalues, eigenvectors=eigenvectors,
+                          residual_bounds=bounds)
 
 
 def clustering_agreement(a: Array, b: Array, k: int) -> float:

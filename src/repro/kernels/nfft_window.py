@@ -25,19 +25,82 @@ stream is amortized over C right-hand sides.  ``d`` is 1..3 (the paper's
 range); the grid is the *padded* grid (``repro.core.nfft.padded_grid_size``)
 so no wrapping logic lives in the kernel — the fold-back of the periodic pad
 is the caller's (cheap, backend-independent) job.
+
+VMEM: the whole padded grid is one resident block, single-buffered (its
+block index never changes).  On the chip the two minor dimensions of a
+block tile as (8 sublanes, 128 lanes), so the channel axis pads to 128
+lanes: a C = 1 grid takes 128x its logical size.  :func:`grid_fits_vmem`
+is the one rule for whether a grid may stay resident; each call asks
+Mosaic for exactly the VMEM its blocks need (:func:`_vmem_limit`).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
 DEFAULT_NODE_TILE = 1024
+
+_SUBLANES, _LANES = 8, 128
+# Largest resident grid block, in VMEM bytes after tiling.  A TPU v5e core
+# has 128 MiB of VMEM; half of it goes to the grid, the rest to the
+# double-buffered node tiles (~10 MiB at the default tile) and Mosaic's own
+# scratch.  Fig. 5's d=3 grid (36^3, N=16 m=2) takes 25.3 MiB and fits; the
+# d=3 SETUP_2 grid (72^3) takes 182 MiB and does not.
+VMEM_GRID_BUDGET = 64 * 2 ** 20
+_VMEM_HEADROOM = 4 * 2 ** 20
+
+
+def _round_up(v: int, k: int) -> int:
+    return -(-v // k) * k
+
+
+def vmem_bytes(shape) -> int:
+    """VMEM bytes of a block of 32-bit values: its two minor dims tile as
+    (8, 128) words."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * _round_up(sub, _SUBLANES)
+            * _round_up(lane, _LANES) * 4)
+
+
+def grid_fits_vmem(padded_size: int, d: int, channels: int) -> bool:
+    """Whether the float32 ``(padded_size,)*d + (channels,)`` grid may stay
+    resident in VMEM (the kernels' one limit)."""
+    return vmem_bytes((padded_size,) * d + (channels,)) <= VMEM_GRID_BUDGET
+
+
+def _vmem_limit(grid_shape, tn: int, d: int, taps: int, c: int) -> int:
+    """Scoped-VMEM request: the resident grid plus double-buffered tiles."""
+    tiles = (vmem_bytes((tn, d)) + vmem_bytes((tn, d, taps))
+             + vmem_bytes((tn, c)))
+    return vmem_bytes(grid_shape) + 2 * tiles + _VMEM_HEADROOM
+
+
+def _tile_map(rank: int):
+    """Index map of a node-tile block: tile ``j``, whole along the rest.
+
+    The zeros take ``j``'s int32 type: a Python ``0`` becomes int64 when
+    x64 is enabled, and Mosaic cannot lower an index map that returns
+    mixed integer widths.
+    """
+    return lambda j: (j,) + (jnp.zeros_like(j),) * (rank - 1)
+
+
+def _resident_map(rank: int):
+    """Index map of the resident grid block (see :func:`_tile_map`)."""
+    return lambda j: (jnp.zeros_like(j),) * rank
+
+
+def _compiler_params(semantics: str, vmem_limit: int):
+    return pltpu.CompilerParams(dimension_semantics=(semantics,),
+                                vmem_limit_bytes=vmem_limit)
 
 
 def _weight_cube(w: Array, d: int) -> Array:
@@ -88,18 +151,22 @@ def window_spread(x: Array, base: Array, weights: Array, *, padded_size: int,
     xp = jnp.pad(x2, ((0, pad), (0, 0)))
     bp = jnp.pad(base, ((0, pad), (0, 0)))
     wp = jnp.pad(weights, ((0, pad), (0, 0), (0, 0)))
+    grid_shape = (padded_size,) * d + (c,)
 
     out = pl.pallas_call(
         functools.partial(_spread_kernel, d=d, taps=taps),
         grid=(xp.shape[0] // tn,),
         in_specs=[
-            pl.BlockSpec((tn, d), lambda j: (j, 0)),
-            pl.BlockSpec((tn, d, taps), lambda j: (j, 0, 0)),
-            pl.BlockSpec((tn, c), lambda j: (j, 0)),
+            pl.BlockSpec((tn, d), _tile_map(2)),
+            pl.BlockSpec((tn, d, taps), _tile_map(3)),
+            pl.BlockSpec((tn, c), _tile_map(2)),
         ],
-        out_specs=pl.BlockSpec((padded_size,) * d + (c,),
-                               lambda j: (0,) * (d + 1)),
-        out_shape=jax.ShapeDtypeStruct((padded_size,) * d + (c,), x2.dtype),
+        out_specs=pl.BlockSpec(grid_shape, _resident_map(d + 1),
+                               pipeline_mode=pl.Buffered(1)),
+        out_shape=jax.ShapeDtypeStruct(grid_shape, x2.dtype),
+        # every tile accumulates into the one resident grid: sequential
+        compiler_params=_compiler_params(
+            "arbitrary", _vmem_limit(grid_shape, tn, d, taps, c)),
         interpret=interpret,
     )(bp, wp, xp)
     return out if batched else out[..., 0]
@@ -132,7 +199,6 @@ def window_gather(grid: Array, base: Array, weights: Array, *,
     batched = grid.ndim == d + 1
     g2 = grid if batched else grid[..., None]
     c = g2.shape[-1]
-    padded_size = g2.shape[0]
     tn = min(node_tile, max(8, n))
     pad = (-n) % tn
     bp = jnp.pad(base, ((0, pad), (0, 0)))  # padded rows read patch 0 * w=0
@@ -142,12 +208,15 @@ def window_gather(grid: Array, base: Array, weights: Array, *,
         functools.partial(_gather_kernel, d=d, taps=taps),
         grid=(bp.shape[0] // tn,),
         in_specs=[
-            pl.BlockSpec((padded_size,) * d + (c,), lambda j: (0,) * (d + 1)),
-            pl.BlockSpec((tn, d), lambda j: (j, 0)),
-            pl.BlockSpec((tn, d, taps), lambda j: (j, 0, 0)),
+            pl.BlockSpec(g2.shape, _resident_map(d + 1),
+                         pipeline_mode=pl.Buffered(1)),
+            pl.BlockSpec((tn, d), _tile_map(2)),
+            pl.BlockSpec((tn, d, taps), _tile_map(3)),
         ],
-        out_specs=pl.BlockSpec((tn, c), lambda j: (j, 0)),
+        out_specs=pl.BlockSpec((tn, c), _tile_map(2)),
         out_shape=jax.ShapeDtypeStruct((bp.shape[0], c), g2.dtype),
+        compiler_params=_compiler_params(
+            "parallel", _vmem_limit(g2.shape, tn, d, taps, c)),
         interpret=interpret,
     )(g2, bp, wp)
     out = out[:n]

@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``interpret`` defaults to True on non-TPU backends so the same call sites work
-on CPU (kernel body executed in Python) and TPU (Mosaic lowering).
+on CPU (kernel body executed in Python) and TPU (Mosaic lowering).  Interpret
+mode is a CPU path only: asking for it on TPU raises.
 """
 
 from __future__ import annotations
@@ -16,8 +17,12 @@ from repro.kernels import nfft_window as _nw
 Array = jax.Array
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is a CPU path; on TPU the "
+                         "kernels run compiled")
+    return not on_tpu if interpret is None else interpret
 
 
 def kernel_matvec(points_out: Array, points_in: Array, x: Array, *,
@@ -33,7 +38,7 @@ def kernel_matvec(points_out: Array, points_in: Array, x: Array, *,
     return _km.kernel_matvec(
         points_out, points_in, x, kernel_name=kernel_name, param=param,
         zero_diagonal=zero_diagonal,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=_interpret(interpret),
         **kw)
 
 
@@ -42,7 +47,7 @@ def window_gather(grid: Array, base: Array, weights: Array, *,
     """Separable-geometry window gather; see repro.kernels.nfft_window."""
     return _nw.window_gather(
         grid, base, weights,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=_interpret(interpret),
         **kw)
 
 
@@ -51,7 +56,7 @@ def window_spread(x: Array, base: Array, weights: Array, *, padded_size: int,
     """Separable-geometry window spread; see repro.kernels.nfft_window."""
     return _nw.window_spread(
         x, base, weights, padded_size=padded_size,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=_interpret(interpret),
         **kw)
 
 
@@ -60,5 +65,5 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
                     interpret: bool | None = None, **kw) -> Array:
     return _fa.flash_attention(
         q, k, v, causal=causal, scale=scale,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=_interpret(interpret),
         **kw)

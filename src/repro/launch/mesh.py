@@ -18,21 +18,30 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes, the kind the LM stack assumes.
+
+    The train step and the model pin GSPMD's propagation with
+    ``with_sharding_constraint`` (``repro.models.common.shard``), which
+    only accepts Auto axes; ``jax.make_mesh`` makes Explicit ones.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_parallel: int = 1) -> Mesh:
     """Mesh over whatever devices exist (CPU smoke / small real runs)."""
     n = jax.device_count()
     assert n % model_parallel == 0, (n, model_parallel)
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def mesh_chip_count(mesh: Mesh) -> int:

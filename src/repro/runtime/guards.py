@@ -19,7 +19,7 @@ bandwidth ``N`` (doubling up to ``GuardPolicy.max_bandwidth``) until the
 bound meets the declared tolerance.  If escalation runs out and the problem
 is small enough, it degrades to the exact O(n^2)
 :class:`DirectKernelOperator` (the bottom rung of the degradation ladder:
-pallas -> xla, pencil -> psum, fastsum -> direct); otherwise it returns the
+pencil -> psum, fastsum -> direct); otherwise it returns the
 best attempt with ``GuardReport.ok = False`` and a warning — degraded,
 never silently wrong.
 """

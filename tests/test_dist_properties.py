@@ -13,11 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import SETUP_1, make_fastsum, make_kernel
 from repro.data.synthetic import spiral
-from repro.dist.compat import shard_map
 from repro.dist.compression import BLOCK, compress_psum
 from repro.dist.fastsum_dist import distributed_matvec_fn
 
@@ -49,7 +49,7 @@ def test_compress_psum_idempotent_on_lattice(seed, exp, n):
     mesh = _mesh1()
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(P(), P()),
-                       out_specs=(P(), P()), check_rep=False)
+                       out_specs=(P(), P()), check_vma=False)
     def run(gs, rs):
         return compress_psum(gs, "data", rs)
 

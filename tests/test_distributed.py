@@ -133,6 +133,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.data.pipeline import batch_for_step
         from repro.dist import sharding as shr
         from repro.launch.steps import shardings_for
+        from repro.launch.mesh import make_mesh
         from repro.models.common import set_mesh
         from repro.training.train_loop import (TrainConfig, init_train_state,
                                                make_train_step)
@@ -146,7 +147,7 @@ def test_sharded_train_step_matches_single_device():
         _, ref = jax.jit(make_train_step(cfg, tc))(state, batch)
         ref_loss = float(ref["loss"])
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         in_sh = shardings_for("train", (state, batch), mesh)
         with mesh, set_mesh(mesh):
             step = jax.jit(make_train_step(cfg, tc), in_shardings=in_sh)
@@ -161,7 +162,7 @@ def test_compress_psum_shard_map():
     run_in_subprocess("""
         import functools, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.dist.compat import shard_map
+        from jax import shard_map
         from repro.dist.compression import compress_psum
 
         mesh = jax.make_mesh((8,), ("data",))
@@ -195,6 +196,7 @@ def test_elastic_restore_across_meshes():
         from repro.configs import get_config, reduced_config
         from repro.data.pipeline import batch_for_step
         from repro.dist import sharding as shr
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import shardings_for
         from repro.models.common import set_mesh
         from repro.training import checkpoint as ckpt
@@ -209,7 +211,7 @@ def test_elastic_restore_across_meshes():
 
         tmp = tempfile.mkdtemp()
         # phase 1: train 2 steps on an (8,1) data-parallel mesh, checkpoint
-        mesh1 = jax.make_mesh((8, 1), ("data", "model"))
+        mesh1 = make_mesh((8, 1), ("data", "model"))
         sh1 = shardings_for("train", (state, batch), mesh1)
         with mesh1, set_mesh(mesh1):
             step1 = jax.jit(make_train_step(cfg, tc), in_shardings=sh1)
@@ -221,7 +223,7 @@ def test_elastic_restore_across_meshes():
         loss_ref = None
 
         # phase 2: restore onto a (2,4) mesh (different DP/TP split), train
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         abstract = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
         sh2 = shardings_for("train", (abstract, batch), mesh2)

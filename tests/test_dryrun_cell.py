@@ -18,6 +18,7 @@ def test_reduced_cell_lower_compile_roofline():
         import dataclasses, jax, jax.numpy as jnp
         from repro.configs import get_config, reduced_config
         from repro.launch import hlo_analysis as H
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import lower_cell
         from repro.training.train_loop import TrainConfig
 
@@ -25,7 +26,7 @@ def test_reduced_cell_lower_compile_roofline():
                              global_batch=8)
         # give the smoke config its real shape list entry
         shape = cfg.shapes[0]
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_mesh((4, 4), ("data", "model"))
         tc = TrainConfig(num_microbatches=2)
         lowered, kind = lower_cell(cfg, shape, mesh, tc=tc)
         assert kind == "train"
@@ -148,12 +149,13 @@ def test_decode_cell_serve_sharding():
     code = """
         import dataclasses, jax
         from repro.configs import get_config, reduced_config
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import lower_cell, _serve_replicated
         from repro.training.train_loop import TrainConfig
 
         cfg = reduced_config(get_config("granite-3-2b"), seq_len=64,
                              global_batch=8)
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_mesh((4, 4), ("data", "model"))
         assert _serve_replicated(cfg, mesh)  # tiny model: TP-resident
         decode = [s for s in cfg.shapes if s.kind == "decode"
                   and not s.skip_reason][0]

@@ -89,7 +89,7 @@ def test_pencil_rfftn_adjoint_roundtrip_parity():
         from repro.core.fastsum import SETUP_1
         from repro.core.fastsum_exec import fused_spectral_multiplier
         from repro.dist import pencil_fft
-        from repro.dist.compat import shard_map
+        from jax import shard_map
 
         mesh = jax.make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
@@ -112,7 +112,7 @@ def test_pencil_rfftn_adjoint_roundtrip_parity():
             @functools.partial(shard_map, mesh=mesh,
                                in_specs=(P(), P(), P()),
                                out_specs=(P(), P(), P()),
-                               check_rep=False)
+                               check_vma=False)
             def run(mult_, x_, y_):
                 rows = grid // spec.row_size
                 r = pencil_fft.group_index(spec.row_axes, spec.row_sizes)
