@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import fastsum_exec, nfft as nfft_mod
+from repro.core import fastsum_exec, nfft as nfft_mod, scopes
 from repro.core.kernels import Kernel
 from repro.core.nfft import (
     NfftGeometry, NfftPlan, WindowGeometry, build_geometry,
@@ -253,18 +253,19 @@ def _scaled_plan(points: Array, params: FastsumParams,
     """
     d = points.shape[1]
     eps_b = params.eps_b_eff
-    if target_points is None:
-        scaled, rho, shift = scale_nodes(points, eps_b)
-        scaled_src = scaled_tgt = scaled
-    else:
-        both = jnp.concatenate([points, target_points], axis=0)
-        scaled, rho, shift = scale_nodes(both, eps_b)
-        scaled_src = scaled[: points.shape[0]]
-        scaled_tgt = scaled[points.shape[0]:]
-    plan = params.nfft_plan(d)
-    src_win = build_window_geometry(plan, scaled_src)
-    tgt_win = src_win if target_points is None \
-        else build_window_geometry(plan, scaled_tgt)
+    with jax.named_scope(scopes.BUILD):
+        if target_points is None:
+            scaled, rho, shift = scale_nodes(points, eps_b)
+            scaled_src = scaled_tgt = scaled
+        else:
+            both = jnp.concatenate([points, target_points], axis=0)
+            scaled, rho, shift = scale_nodes(both, eps_b)
+            scaled_src = scaled[: points.shape[0]]
+            scaled_tgt = scaled[points.shape[0]:]
+        plan = params.nfft_plan(d)
+        src_win = build_window_geometry(plan, scaled_src)
+        tgt_win = src_win if target_points is None \
+            else build_window_geometry(plan, scaled_tgt)
     return (scaled_src, None if target_points is None else scaled_tgt,
             rho, plan, src_win, tgt_win)
 
@@ -279,17 +280,18 @@ def _member_spectral(kernel: Kernel, rho, plan: NfftPlan,
     # rho may be a concrete scalar (eager planning) or a tracer (operator
     # construction / re-spectralization under jit or grad) — Kernel carries
     # traced parameters natively, so no concretization is needed here.
-    rescaled_kernel = kernel.rescaled(rho)
-    b_hat = kernel_fourier_coefficients(rescaled_kernel, plan.d,
-                                        params.n_bandwidth, params.p_eff,
-                                        params.eps_b_eff)
-    mult_half = fastsum_exec.fused_spectral_multiplier(plan, b_hat)
-    exponent = kernel.output_scale_exponent
-    out_scale = rho ** exponent if exponent != 0 else 1.0
-    # K(0) is scale-invariant for all four kernels w/ parameter rescaling
-    # *except* the multiquadrics, where K(0)=c resp. 1/c;
-    # out_scale * K_rescaled(0) == K(0) holds for all four — use that:
-    k0_corr = out_scale * rescaled_kernel.at_zero()
+    with jax.named_scope(scopes.BUILD):
+        rescaled_kernel = kernel.rescaled(rho)
+        b_hat = kernel_fourier_coefficients(rescaled_kernel, plan.d,
+                                            params.n_bandwidth, params.p_eff,
+                                            params.eps_b_eff)
+        mult_half = fastsum_exec.fused_spectral_multiplier(plan, b_hat)
+        exponent = kernel.output_scale_exponent
+        out_scale = rho ** exponent if exponent != 0 else 1.0
+        # K(0) is scale-invariant for all four kernels w/ parameter
+        # rescaling *except* the multiquadrics, where K(0)=c resp. 1/c;
+        # out_scale * K_rescaled(0) == K(0) holds for all four — use that:
+        k0_corr = out_scale * rescaled_kernel.at_zero()
     return b_hat, mult_half, out_scale, k0_corr
 
 
@@ -640,13 +642,16 @@ class NormalizedAdjacencyOperator:
 
 
 def _normalized_adjacency_from(fs: FastsumOperator) -> NormalizedAdjacencyOperator:
-    deg = fs.degrees()
-    # Lemma 3.1 requires eps < eta, i.e. the approximation error below the
-    # smallest degree; negative approximate degrees would make D^{-1/2}
-    # imaginary (the classical-Nyström failure mode the paper highlights).
-    deg = jnp.maximum(deg, jnp.finfo(deg.dtype).tiny)
+    with jax.named_scope(scopes.BUILD):
+        deg = fs.degrees()
+        # Lemma 3.1 requires eps < eta, i.e. the approximation error below
+        # the smallest degree; negative approximate degrees would make
+        # D^{-1/2} imaginary (the classical-Nyström failure mode the paper
+        # highlights).
+        deg = jnp.maximum(deg, jnp.finfo(deg.dtype).tiny)
+        inv_sqrt_deg = 1.0 / jnp.sqrt(deg)
     return NormalizedAdjacencyOperator(
-        fastsum=fs, inv_sqrt_deg=1.0 / jnp.sqrt(deg), degrees=deg
+        fastsum=fs, inv_sqrt_deg=inv_sqrt_deg, degrees=deg
     )
 
 

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core.nfft import (
     NfftPlan, WindowGeometry, _embed_map, padded_grid_size, window_shift,
 )
@@ -324,22 +325,25 @@ def window_spread(plan: NfftPlan, geometry: WindowGeometry, x: Array, *,
     """
     d, grid, taps = plan.d, plan.grid_size, plan.taps
     pad_n = padded_grid_size(plan)
-    # align node values with the Morton-sorted rows
-    xs = x if geometry.perm is None else x[geometry.perm]
-    if resolve_backend(backend, plan, xs.shape[-1], xs.dtype) == "pallas":
-        gpad = kernel_ops.window_spread(xs, geometry.base, geometry.weights,
-                                        padded_size=pad_n)
-    else:
-        gpad = _xla_spread(plan, geometry, xs)
-    # fold the periodic pad back: unwrapped u and u - M are the same cell
-    ext = taps - 1
-    for ax in range(d):
-        main = jax.lax.slice_in_dim(gpad, 0, grid, axis=ax)
-        tail = jax.lax.slice_in_dim(gpad, grid, pad_n, axis=ax)
-        idx = (slice(None),) * ax + (slice(0, ext),)
-        gpad = main.at[idx].add(tail)
-    # padded coordinate u <-> FFT-order index (u - shift) mod M
-    return jnp.roll(gpad, (-window_shift(plan),) * d, axis=tuple(range(d)))
+    with jax.named_scope(scopes.SPREAD):
+        # align node values with the Morton-sorted rows
+        xs = x if geometry.perm is None else x[geometry.perm]
+        if resolve_backend(backend, plan, xs.shape[-1], xs.dtype) == "pallas":
+            gpad = kernel_ops.window_spread(xs, geometry.base,
+                                            geometry.weights,
+                                            padded_size=pad_n)
+        else:
+            gpad = _xla_spread(plan, geometry, xs)
+        # fold the periodic pad back: unwrapped u and u - M are the same cell
+        ext = taps - 1
+        for ax in range(d):
+            main = jax.lax.slice_in_dim(gpad, 0, grid, axis=ax)
+            tail = jax.lax.slice_in_dim(gpad, grid, pad_n, axis=ax)
+            idx = (slice(None),) * ax + (slice(0, ext),)
+            gpad = main.at[idx].add(tail)
+        # padded coordinate u <-> FFT-order index (u - shift) mod M
+        return jnp.roll(gpad, (-window_shift(plan),) * d,
+                        axis=tuple(range(d)))
 
 
 def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
@@ -351,20 +355,23 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
     selected backend, then restore node order.
     """
     d, taps = plan.d, plan.taps
-    rolled = jnp.roll(g, (window_shift(plan),) * d, axis=tuple(range(d)))
-    gpad = jnp.pad(rolled, [(0, taps - 1)] * d + [(0, 0)], mode="wrap")
-    if resolve_backend(backend, plan, g.shape[-1], g.dtype) == "pallas":
-        out = kernel_ops.window_gather(gpad, geometry.base, geometry.weights)
-    else:
-        out = _xla_gather(plan, geometry, gpad)
-    if geometry.perm is None:
-        return out
-    # restore node order via the inverse permutation as a row *take*: the
-    # equivalent multi-channel row scatter costs ~10x more on XLA CPU, and
-    # the (n,) int scatter building the inverse is single-channel (cheap)
-    inv = jnp.zeros_like(geometry.perm).at[geometry.perm].set(
-        jnp.arange(out.shape[0], dtype=geometry.perm.dtype))
-    return out[inv]
+    with jax.named_scope(scopes.GATHER):
+        rolled = jnp.roll(g, (window_shift(plan),) * d, axis=tuple(range(d)))
+        gpad = jnp.pad(rolled, [(0, taps - 1)] * d + [(0, 0)], mode="wrap")
+        if resolve_backend(backend, plan, g.shape[-1], g.dtype) == "pallas":
+            out = kernel_ops.window_gather(gpad, geometry.base,
+                                           geometry.weights)
+        else:
+            out = _xla_gather(plan, geometry, gpad)
+        if geometry.perm is None:
+            return out
+        # restore node order via the inverse permutation as a row *take*:
+        # the equivalent multi-channel row scatter costs ~10x more on XLA
+        # CPU, and the (n,) int scatter building the inverse is
+        # single-channel (cheap)
+        inv = jnp.zeros_like(geometry.perm).at[geometry.perm].set(
+            jnp.arange(out.shape[0], dtype=geometry.perm.dtype))
+        return out[inv]
 
 
 # ---------------------------------------------------------------------------
@@ -403,10 +410,12 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
 def _spectral_mid(plan: NfftPlan, multiplier_half: Array, g: Array) -> Array:
     """rfftn -> multiply -> irfftn on the spread grid (single multiplier)."""
     d = plan.d
-    g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-    g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
-    y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d, axes=tuple(range(d)))
-    return y.astype(g.dtype)
+    with jax.named_scope(scopes.FFT_MID):
+        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
+        g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
+        y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
+                           axes=tuple(range(d)))
+        return y.astype(g.dtype)
 
 
 def _bank_multiply(plan: NfftPlan, multiplier_bank: Array, g_hat: Array,
@@ -428,10 +437,12 @@ def _bank_spectral_mid(plan: NfftPlan, broadcast: bool,
                        multiplier_bank: Array, g: Array) -> Array:
     """Bank rfftn -> member-wise multiply -> irfftn (no reduce/op hooks)."""
     d = plan.d
-    g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-    flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
-    y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d, axes=tuple(range(d)))
-    return y.astype(g.dtype)
+    with jax.named_scope(scopes.FFT_MID):
+        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
+        flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
+        y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d,
+                           axes=tuple(range(d)))
+        return y.astype(g.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -541,17 +552,18 @@ def fused_pipeline(plan: NfftPlan, multiplier_half: Array,
     g = window_spread(plan, src, xb, backend=backend)
     if grid_hook is not None:
         g = grid_hook(g)
-    if spectral_op is not None:
-        y = spectral_op(g)
-    else:
-        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-        g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
-        if spectral_reduce is not None:
-            sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
-            block = spectral_reduce(g_hat[tuple(sup)])
-            g_hat = jnp.zeros_like(g_hat).at[tuple(sup)].set(block)
-        y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
-                           axes=tuple(range(d)))
+    with jax.named_scope(scopes.FFT_MID):
+        if spectral_op is not None:
+            y = spectral_op(g)
+        else:
+            g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
+            g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
+            if spectral_reduce is not None:
+                sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
+                block = spectral_reduce(g_hat[tuple(sup)])
+                g_hat = jnp.zeros_like(g_hat).at[tuple(sup)].set(block)
+            y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
+                               axes=tuple(range(d)))
     out = window_gather(plan, tgt, y.astype(xb.dtype), backend=backend)
     return out if batched else out[..., 0]
 
@@ -651,18 +663,20 @@ def _bank_columns_transform(plan: NfftPlan, multiplier_bank: Array,
     """
     d = plan.d
     g = window_spread(plan, src, xb, backend=backend)
-    if spectral_op is not None:
-        y = spectral_op(g)  # (M,)*d + (S*C,): the op owns the bank multiply
-    else:
-        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-        flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
-        if spectral_reduce is not None:
-            sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
-            block = spectral_reduce(flat[tuple(sup)])
-            flat = jnp.zeros_like(flat).at[tuple(sup)].set(block)
-        y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d,
-                           axes=tuple(range(d)))
-    return y.astype(xb.dtype)
+    with jax.named_scope(scopes.FFT_MID):
+        if spectral_op is not None:
+            # (M,)*d + (S*C,): the op owns the bank multiply
+            y = spectral_op(g)
+        else:
+            g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
+            flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
+            if spectral_reduce is not None:
+                sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
+                block = spectral_reduce(flat[tuple(sup)])
+                flat = jnp.zeros_like(flat).at[tuple(sup)].set(block)
+            y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d,
+                               axes=tuple(range(d)))
+        return y.astype(xb.dtype)
 
 
 def _bank_columns_core(plan: NfftPlan, multiplier_bank: Array,

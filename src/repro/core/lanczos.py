@@ -30,6 +30,8 @@ from typing import Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 Array = jax.Array
 Matvec = Callable[[Array], Array]
 
@@ -101,10 +103,11 @@ def lanczos_machine(matvec: Matvec, v0: Array, num_iters: int,
         w = w - alpha * qi - jnp.where(i > 0, betas[i], 0.0) * basis[jnp.maximum(i - 1, 0)]
         if reorthogonalize:
             # two-pass CGS against the filled part of the basis
-            mask = (jnp.arange(num_iters) <= i)[:, None].astype(dtype)
-            for _ in range(2):
-                coeffs = _mm(basis * mask, w)
-                w = w - _mm((basis * mask).T, coeffs)
+            with jax.named_scope(scopes.KRYLOV_ORTH):
+                mask = (jnp.arange(num_iters) <= i)[:, None].astype(dtype)
+                for _ in range(2):
+                    coeffs = _mm(basis * mask, w)
+                    w = w - _mm((basis * mask).T, coeffs)
         beta = jnp.linalg.norm(w)
         # breakdown guard: a non-finite recurrence step (poisoned matvec)
         # truncates the factorization — nothing at/after it is ever
@@ -193,14 +196,17 @@ def block_lanczos_machine(matvec: Matvec, v0: Array, num_blocks: int,
         w = w - _mm(qj, a)
         w = w - jnp.where(j > 0, 1.0, 0.0) * _mm(
             basis[jnp.maximum(j - 1, 0)], b_blocks[j].T)
-        if reorthogonalize:
-            # two-pass block CGS against the filled part of the basis
-            mask = (jnp.arange(num_blocks) <= j)[:, None, None].astype(dtype)
-            flat = jnp.moveaxis(basis * mask, 1, 0).reshape(n, num_blocks * b)
-            for _ in range(2):
-                coeffs = _mm(flat.T, w)  # (blocks*b, b)
-                w = w - _mm(flat, coeffs)
-        q_next, r_next = jnp.linalg.qr(w)
+        with jax.named_scope(scopes.KRYLOV_ORTH):
+            if reorthogonalize:
+                # two-pass block CGS against the filled part of the basis
+                mask = (jnp.arange(num_blocks) <= j)[:, None, None].astype(
+                    dtype)
+                flat = jnp.moveaxis(basis * mask, 1, 0).reshape(
+                    n, num_blocks * b)
+                for _ in range(2):
+                    coeffs = _mm(flat.T, w)  # (blocks*b, b)
+                    w = w - _mm(flat, coeffs)
+            q_next, r_next = jnp.linalg.qr(w)
         # breakdown guard: truncate the factorization at the first block
         # step with a non-finite recurrence (see ``lanczos``)
         alive = j < breakdown
@@ -413,13 +419,14 @@ def eigsh(matvec: Matvec, n: int, k: int, *, num_iters: int | None = None,
     (the fused fastsum engine executes a block in one spread/FFT/gather
     pass).  The matvec callable must accept (n, C) input in that case.
     """
-    setup = eigsh_setup(n, k, num_iters=num_iters, which=which, key=key,
-                        dtype=dtype, v0=v0, block_size=block_size)
-    if setup.num_blocks:
-        res = block_lanczos(matvec, setup.v0, setup.num_blocks)
-        return ritz_from_block(res, setup, n)
-    res = lanczos(matvec, setup.v0, setup.num_iters)
-    return ritz_from_lanczos(res, setup)
+    with jax.named_scope(scopes.KRYLOV):
+        setup = eigsh_setup(n, k, num_iters=num_iters, which=which, key=key,
+                            dtype=dtype, v0=v0, block_size=block_size)
+        if setup.num_blocks:
+            res = block_lanczos(matvec, setup.v0, setup.num_blocks)
+            return ritz_from_block(res, setup, n)
+        res = lanczos(matvec, setup.v0, setup.num_iters)
+        return ritz_from_lanczos(res, setup)
 
 
 def eigsh_smallest_laplacian(adjacency_matvec: Matvec, n: int, k: int,

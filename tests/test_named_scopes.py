@@ -1,0 +1,158 @@
+"""The program's layer scopes (``repro.core.scopes``): each one is in the
+compiled eigensolve program, on every path that runs the operator, and the
+program compiled with them is the program compiled without them once the
+op-name metadata is stripped."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import (FastsumParams, eigsh, fused_pipeline, make_fastsum,
+                        make_kernel, make_normalized_adjacency, scopes)
+from repro.graph.spectral import spectral_clustering
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+# the compiled program's text opens with tables of the source lines that
+# made each operation (FileNames, FunctionNames, FileLocations,
+# StackFrames), up to its first computation
+_DEBUG_TABLES = re.compile(r"^FileNames\n.*?(?=^(?:%|ENTRY ))", re.S | re.M)
+
+# (block size, k-means) of the two eigensolve jobs: Lanczos with k-means on
+# its eigenvectors, and block Lanczos
+JOBS = [(1, True), (4, False)]
+
+
+def job_program(block: int, kmeans: bool):
+    """The benchmark's eigensolve job at a tiny size: build the normalized
+    adjacency of the points, ``eigsh`` on it, k-means on the eigenvectors."""
+    kernel = make_kernel("gaussian", sigma=1.0)
+    params = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125)
+    k = 4
+
+    def program(points, key):
+        op = make_normalized_adjacency(kernel, points, params)
+        res = eigsh(op.matvec, op.n, k, key=key, block_size=block,
+                    dtype=op.inv_sqrt_deg.dtype)
+        out = [op.degrees, res.eigenvalues, res.eigenvectors]
+        if kmeans:
+            out.append(spectral_clustering(
+                op, k, key=key, eigenvectors=res.eigenvectors,
+                eigenvalues=res.eigenvalues).assignments)
+        return out
+
+    return program
+
+
+def compiled_text(fn, *args) -> str:
+    jax.clear_caches()  # trace anew: a cached jaxpr keeps its op names
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def job_text(block: int, kmeans: bool) -> str:
+    points = jax.random.normal(jax.random.PRNGKey(0), (96, 3))
+    return compiled_text(job_program(block, kmeans), points,
+                         jax.random.PRNGKey(1))
+
+
+def strip_metadata(hlo_text: str) -> str:
+    return _DEBUG_TABLES.sub("", _METADATA.sub("", hlo_text))
+
+
+def found_scopes(hlo_text: str, where=lambda name: True) -> set:
+    return {scopes.innermost(name) for name in _OP_NAME.findall(hlo_text)
+            if where(name)} - {None}
+
+
+@pytest.fixture(scope="module")
+def scoped_jobs():
+    return {job: job_text(*job) for job in JOBS}
+
+
+@pytest.mark.parametrize("job", JOBS)
+def test_every_scope_is_in_the_job_program(scoped_jobs, job):
+    assert found_scopes(scoped_jobs[job]) == set(scopes.SCOPES)
+
+
+@pytest.mark.parametrize("job", JOBS)
+def test_scopes_change_nothing_but_op_name_metadata(scoped_jobs, job,
+                                                   monkeypatch):
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = job_text(*job)
+    assert found_scopes(bare) == set()
+    stripped = strip_metadata(bare)
+    # every computation is kept, and nothing of the metadata
+    assert stripped.count("\nENTRY ") == 1
+    assert "op_name" not in stripped and "\nStackFrames\n" not in stripped
+    assert strip_metadata(scoped_jobs[job]) == stripped
+
+
+def _operator(n: int = 64, d: int = 2):
+    points = jax.random.normal(jax.random.PRNGKey(2), (n, d))
+    return make_fastsum(make_kernel("gaussian", sigma=1.0), points,
+                        FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125))
+
+
+def test_the_hooked_pipeline_carries_the_operator_scopes():
+    """The distributed matvec's path: a spectral hook in place of the
+    multiply's reduce bypasses the custom VJP."""
+    fs = _operator()
+    text = compiled_text(
+        lambda x: fused_pipeline(fs.plan, fs.multiplier_half, fs.src_window,
+                                 fs.tgt_window, x,
+                                 spectral_reduce=lambda block: 2.0 * block),
+        jnp.ones(fs.n_source))
+    assert found_scopes(text) == {scopes.SPREAD, scopes.FFT_MID,
+                                  scopes.GATHER}
+
+
+def test_the_custom_vjp_backward_carries_the_operator_scopes():
+    fs = _operator()
+
+    def loss(x, multiplier):
+        return jnp.sum(fused_pipeline(fs.plan, multiplier, fs.src_window,
+                                      fs.tgt_window, x) ** 2)
+
+    text = compiled_text(jax.grad(loss, argnums=(0, 1)),
+                         jnp.ones(fs.n_source), fs.multiplier_half)
+    backward = found_scopes(text, where=lambda name: "transpose(" in name)
+    assert backward == {scopes.SPREAD, scopes.FFT_MID, scopes.GATHER}
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    # the Pallas kernels' custom calls, in the block solver's loop
+    ("jit(program)/krylov/while/body/closed_call/jit(fused_matvec_tilde)/"
+     "spread/jit(window_spread)/pallas_call", "spread"),
+    ("jit(program)/krylov/while/body/closed_call/jit(fused_matvec_tilde)/"
+     "gather/jit(window_gather)/pallas_call", "gather"),
+    # the XLA window path: the spread's scatter in its loop over node tiles,
+    # the gather's own gather primitive
+    ("jit(program)/build/jit(fused_matvec_tilde)/spread/while/body/"
+     "closed_call/scatter-add", "spread"),
+    ("jit(program)/krylov/while/body/closed_call/jit(fused_matvec_tilde)/"
+     "gather/while/body/closed_call/gather", "gather"),
+    ("jit(program)/krylov/jit(fused_matvec_tilde)/fft_mid/jit(fft)/fft",
+     "fft_mid"),
+    ("jit(program)/krylov/while/body/krylov_orth/dot_general",
+     "krylov_orth"),
+    ("jit(program)/krylov/while/body/krylov_orth/jit(qr)/geqrf",
+     "krylov_orth"),
+    ("jit(program)/build/jit(build_window_geometry)/sort", "build"),
+    ("jit(program)/krylov/jit(eigh)/eigh", "krylov"),
+    # a scope under a transformation: the custom VJP's backward pass
+    ("jit(f)/transpose(jvp(spread))/scatter-add", "spread"),
+    ("jit(f)/transpose(jvp(transpose(jvp(fft_mid))))/jit(fft)/fft",
+     "fft_mid"),
+    # the primitive named gather, or a jitted function, is no scope
+    ("jit(program)/jit(kmeans)/gather", None),
+    ("jit(program)/jit(fft)/fft", None),
+    ("jit(f)/jvp()/mul", None),
+    ("scatter-add", None),
+    ("", None),
+])
+def test_innermost(op_name, scope):
+    assert scopes.innermost(op_name) == scope

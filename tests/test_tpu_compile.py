@@ -12,15 +12,19 @@ fixture runs in the one test worker given this file and skips where the
 topology cannot be described.
 """
 
+import base64
+import contextlib
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.fastsum import SETUP_2, SETUP_3, FastsumParams
-from repro.core.fastsum_exec import resolve_backend
-from repro.core.nfft import padded_grid_size
+from repro.core.fastsum_exec import fused_pipeline, resolve_backend
+from repro.core.nfft import WindowGeometry, padded_grid_size
 from repro.kernels import nfft_window
 
 FIG5 = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=1.0 / 8.0)
@@ -95,3 +99,65 @@ def test_backend_rule_picks_xla_where_the_grid_cannot_stay_resident(
     # Mosaic has no 64-bit floats: float64 data takes the XLA path on TPU
     assert resolve_backend("auto", FIG5.nfft_plan(3), 1,
                            jnp.float64) == "xla"
+
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_DEBUG_TABLES = re.compile(r"^FileNames\n.*?(?=^(?:%|ENTRY ))", re.S | re.M)
+_KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
+
+
+def _kernel_without_locations(match) -> str:
+    """A Pallas kernel's serialized Mosaic module, printed without its
+    source locations (which hold the op names of the scopes around it)."""
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+
+    with mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(match.group(1)))
+        return json.dumps(module.operation.get_asm(enable_debug_info=False))
+
+
+def _without_metadata(hlo_text: str) -> str:
+    text = _DEBUG_TABLES.sub("", _METADATA.sub("", hlo_text))
+    return _KERNEL_BODY.sub(_kernel_without_locations, text)
+
+
+def _pallas_matvec_text(sharding, n: int = 4096) -> str:
+    """The fused matvec at Fig. 5's setup on the Pallas window kernels,
+    compiled for the described chip."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    plan = FIG5.nfft_plan(3)
+    grid = plan.grid_size
+    geometry = WindowGeometry(base=spec((n, 3), jnp.int32),
+                              weights=spec((n, 3, plan.taps), jnp.float32),
+                              perm=spec((n,), jnp.int32))
+    jax.clear_caches()  # trace anew: a cached jaxpr keeps its op names
+    return jax.jit(lambda m, g, x: fused_pipeline(
+        plan, m, g, g, x, backend="pallas")).lower(
+            spec((grid, grid, grid // 2 + 1), jnp.float32), geometry,
+            spec((n, 1), jnp.float32)).compile().as_text()
+
+
+def test_scopes_leave_the_compiled_pallas_matvec_unchanged(one_chip,
+                                                            monkeypatch):
+    """The program's scopes (``repro.core.scopes``) around the Pallas
+    kernels reach the kernels' serialized modules only as source
+    locations: stripped of those and of the op-name metadata, the program
+    is the one compiled without the scopes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    scoped = _pallas_matvec_text(one_chip)
+    assert scoped.count('custom_call_target="tpu_custom_call"') == 2
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _pallas_matvec_text(one_chip)
+
+    def op_metadata_stripped(text):
+        return _DEBUG_TABLES.sub("", _METADATA.sub("", text))
+
+    # the kernels' modules differ, in their source locations alone
+    assert op_metadata_stripped(scoped) != op_metadata_stripped(bare)
+    assert _without_metadata(scoped) == _without_metadata(bare)
+    assert _without_metadata(bare).count("\nENTRY ") == 1
