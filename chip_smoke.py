@@ -305,8 +305,7 @@ def phase_four_chips(n: int = 100_000) -> None:
     pts = jnp.asarray(points, jnp.float32)
     kernel = make_kernel("gaussian", sigma=SPIRAL_SIGMA)
     x = jax.random.normal(jax.random.PRNGKey(6), (n,), jnp.float32)
-    # SETUP_1 runs the Pallas window kernels inside shard_map, SETUP_2 the
-    # XLA path (its d=3 grid does not fit VMEM)
+    # both setups run the Pallas window kernels inside shard_map
     ops = {}
     for setup_name, setup in (("SETUP_2", SETUP_2), ("SETUP_1", SETUP_1)):
         op = ops[setup_name] = make_normalized_adjacency(kernel, pts, setup)

@@ -39,14 +39,17 @@ backends selected by ``backend="auto"|"xla"|"pallas"``:
   path is never materialized.
 
 * ``"pallas"`` (`repro.kernels.nfft_window`): Morton-sorted node tiles
-  stream through VMEM against the resident padded grid; each node
+  stream through VMEM against the resident padded grid, held lane-dense
+  (last spatial axis on the lanes, channels leading); each node
   scatter-adds into / gathers from only the (taps,)^d patch it touches,
-  with the weight tensor product and batched channels kept in-register.
+  with the weight tensor product kept in-register.  The window step
+  converts between the engine's ``(P,)*d + (C,)`` grid and that layout,
+  and splits the channels into as few kernel calls as VMEM holds.
 
-``backend="auto"`` (the default everywhere) picks pallas on TPU when the
-resident grid fits VMEM and xla otherwise (:func:`resolve_backend`), so
-``FastsumOperator.matvec``, block Lanczos, and the distributed matvec pick
-the kernels up transparently.
+``backend="auto"`` (the default everywhere) picks pallas on TPU when one
+channel of the resident grid fits VMEM and xla otherwise
+(:func:`resolve_backend`), so ``FastsumOperator.matvec``, block Lanczos,
+and the distributed matvec pick the kernels up transparently.
 
 Everything is natively multi-RHS: ``x`` of shape (n,) or (n, C) flows
 through with a trailing channel dimension on the grid, so block Lanczos /
@@ -77,13 +80,15 @@ def resolve_backend(backend: str | None, plan: NfftPlan, channels: int,
     """Resolve the window-step backend for one spread or gather.
 
     ``"auto"`` picks the Pallas kernels on TPU when their inputs are
-    float32 and the resident padded grid of ``channels`` lanes fits VMEM
+    float32 and one channel of the resident padded grid fits VMEM
     (:func:`repro.kernels.nfft_window.grid_fits_vmem`, the one threshold),
     and the XLA path otherwise: off TPU, for float64 (Mosaic has no 64-bit
-    floats), and for grids too large to stay resident (d=3 at SETUP_2/3).
-    Everything it reads is static, so the choice is made once per traced
-    shape, and a kernel that then fails to compile raises — nothing falls
-    back behind the caller's back.
+    floats), and for grids too large to stay resident.  ``channels`` does
+    not change the choice: more channels than one call holds run in
+    chunks that fit (:func:`_channel_chunks`).  Everything it reads is
+    static, so the choice is made once per traced shape, and a kernel
+    that then fails to compile raises — nothing falls back behind the
+    caller's back.
 
     An *explicit* ``"pallas"`` off-TPU runs the kernels in interpret mode —
     the per-node streaming loop executed by the Pallas emulator.  That is
@@ -94,8 +99,7 @@ def resolve_backend(backend: str | None, plan: NfftPlan, channels: int,
         if jax.default_backend() != "tpu" or \
                 jnp.dtype(dtype) != jnp.float32:
             return "xla"
-        fits = nfft_window.grid_fits_vmem(padded_grid_size(plan), plan.d,
-                                          channels)
+        fits = nfft_window.grid_fits_vmem(padded_grid_size(plan), plan.d, 1)
         return "pallas" if fits else "xla"
     if backend not in ("xla", "pallas"):
         raise ValueError(
@@ -316,6 +320,37 @@ def _xla_gather(plan: NfftPlan, geometry: WindowGeometry,
     return jnp.moveaxis(out, 0, 1)
 
 
+def _channel_chunks(plan: NfftPlan, channels: int) -> list:
+    """Channel slices of the Pallas calls: as few as hold the grid in
+    VMEM (:func:`repro.kernels.nfft_window.channels_per_call`)."""
+    width = nfft_window.channels_per_call(padded_grid_size(plan), plan.d,
+                                          channels)
+    return [slice(k, min(k + width, channels))
+            for k in range(0, channels, width)]
+
+
+def _pallas_spread(plan: NfftPlan, geometry: WindowGeometry,
+                   xs: Array) -> Array:
+    """The Pallas spread, channel chunk by chunk, in the engine's
+    ``(P,)*d + (C,)`` layout."""
+    pad_n = padded_grid_size(plan)
+    blocks = [kernel_ops.window_spread(xs[:, cs], geometry.base,
+                                       geometry.weights, padded_size=pad_n)
+              for cs in _channel_chunks(plan, xs.shape[-1])]
+    return nfft_window.from_grid_block(jnp.concatenate(blocks, axis=0),
+                                       pad_n, plan.d)
+
+
+def _pallas_gather(plan: NfftPlan, geometry: WindowGeometry,
+                   gpad: Array) -> Array:
+    """The Pallas gather of a ``(P,)*d + (C,)`` grid, chunk by chunk."""
+    block = nfft_window.to_grid_block(gpad, plan.d)
+    outs = [kernel_ops.window_gather(block[cs], geometry.base,
+                                     geometry.weights)
+            for cs in _channel_chunks(plan, gpad.shape[-1])]
+    return jnp.concatenate(outs, axis=1)
+
+
 def window_spread(plan: NfftPlan, geometry: WindowGeometry, x: Array, *,
                   backend: str | None = None) -> Array:
     """Spread node values (n, C) onto the oversampled grid -> (M,)*d + (C,).
@@ -329,9 +364,7 @@ def window_spread(plan: NfftPlan, geometry: WindowGeometry, x: Array, *,
         # align node values with the Morton-sorted rows
         xs = x if geometry.perm is None else x[geometry.perm]
         if resolve_backend(backend, plan, xs.shape[-1], xs.dtype) == "pallas":
-            gpad = kernel_ops.window_spread(xs, geometry.base,
-                                            geometry.weights,
-                                            padded_size=pad_n)
+            gpad = _pallas_spread(plan, geometry, xs)
         else:
             gpad = _xla_spread(plan, geometry, xs)
         # fold the periodic pad back: unwrapped u and u - M are the same cell
@@ -359,8 +392,7 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
         rolled = jnp.roll(g, (window_shift(plan),) * d, axis=tuple(range(d)))
         gpad = jnp.pad(rolled, [(0, taps - 1)] * d + [(0, 0)], mode="wrap")
         if resolve_backend(backend, plan, g.shape[-1], g.dtype) == "pallas":
-            out = kernel_ops.window_gather(gpad, geometry.base,
-                                           geometry.weights)
+            out = _pallas_gather(plan, geometry, gpad)
         else:
             out = _xla_gather(plan, geometry, gpad)
         if geometry.perm is None:

@@ -63,6 +63,35 @@ def test_distributed_fastsum_matches_single_device():
     """)
 
 
+def test_distributed_matvec_on_the_lane_dense_kernels():
+    """The distributed matvec's local window step on the Pallas kernels
+    (interpret mode on the CPU), in both spectral modes, at d = 2 and 3,
+    against the one-device matvec on the XLA window path."""
+    out = run_in_subprocess("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import FastsumParams, make_fastsum, make_kernel
+        from repro.dist.fastsum_dist import distributed_matvec_fn
+
+        rng = np.random.default_rng(0)
+        mesh = jax.make_mesh((4,), ("data",))
+        for d in (2, 3):
+            pts = jnp.asarray(rng.normal(size=(256, d)), jnp.float32)
+            op = make_fastsum(make_kernel("gaussian", sigma=2.0), pts,
+                              FastsumParams(n_bandwidth=16, m=2))
+            x = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
+            ref = op.matvec(x, backend="xla")
+            for mode in ("psum", "pencil"):
+                mv = distributed_matvec_fn(op, mesh, ("data",),
+                                           backend="pallas",
+                                           spectral_mode=mode)
+                err = float(jnp.linalg.norm(mv(x) - ref)
+                            / jnp.linalg.norm(ref))
+                assert err < 1e-5, (d, mode, err)
+                print("OK", d, mode, err)
+    """, devices=4)
+    assert out.count("OK") == 4
+
+
 def test_distributed_bank_matvec_matches_single_device():
     """Operator-bank routing through the sharded matvec (PR 5): both
     spectral modes, broadcast and lockstep flavors, ghost-padded n, parity

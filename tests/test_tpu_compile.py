@@ -29,10 +29,12 @@ from repro.kernels import nfft_window
 
 FIG5 = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=1.0 / 8.0)
 
-# (name, nodes, plan): Fig. 5 segmentation (426,400 RGB pixels), and the
-# Fig. 3 / SSL accuracy setups at d = 2 and d = 1.
+# (name, nodes, plan): Fig. 5 segmentation (426,400 RGB pixels), the
+# Fig. 3 spiral at SETUP_2 (d = 3), and the Fig. 3 / SSL accuracy setups
+# at d = 2 and d = 1.
 SHAPES = {
     "fig5_d3": (426_400, FIG5.nfft_plan(3)),
+    "spiral_setup2_d3": (100_000, SETUP_2.nfft_plan(3)),
     "setup2_d2": (100_000, SETUP_2.nfft_plan(2)),
     "setup3_d1": (100_000, SETUP_3.nfft_plan(1)),
 }
@@ -68,7 +70,8 @@ def _compile(kind: str, n: int, plan, channels: int, sharding):
         args = (spec((n, channels), jnp.float32), base, weights)
     else:
         fn = jax.jit(lambda g, b, w: nfft_window.window_gather(g, b, w))
-        args = (spec((pad,) * d + (channels,), jnp.float32), base, weights)
+        args = (spec(nfft_window.grid_block_shape(pad, d, channels),
+                     jnp.float32), base, weights)
     return fn.lower(*args).compile()
 
 
@@ -85,20 +88,70 @@ def test_window_kernel_compiles_for_v5e(one_chip, kind, shape, channels):
 
 def test_backend_rule_picks_xla_where_the_grid_cannot_stay_resident(
         one_chip, monkeypatch):
-    """d=3 SETUP_2: the padded 72^3 grid needs 182 MiB of VMEM after lane
-    padding; the rule picks the XLA path, and the compiler agrees that the
-    kernel cannot be built there."""
-    plan = SETUP_2.nfft_plan(3)
-    assert not nfft_window.grid_fits_vmem(padded_grid_size(plan), 3, 1)
+    """A d=3 grid of which not even one channel fits VMEM (N = 256: a
+    516^3 padded grid, 687 MB per channel lane-dense) takes the XLA path,
+    and the compiler agrees that the kernel cannot be built there.  A grid
+    whose channels do not all fit runs in chunks that do: SETUP_3 d=3
+    (142^3, 20.9 MB of grid per channel) holds 3 channels per call, and the
+    compiler refuses its whole 8-channel block (167 MB).  d=3 SETUP_2, on
+    the XLA path while its grid padded the channels to 128 lanes, now
+    takes the kernels."""
+    huge = FastsumParams(n_bandwidth=256, m=2).nfft_plan(3)
+    assert not nfft_window.grid_fits_vmem(padded_grid_size(huge), 3, 1)
     with pytest.raises(Exception, match="(?i)vmem|exceed memory"):
-        _compile("gather", 100_000, plan, 1, one_chip)
+        _compile("gather", 100_000, huge, 1, one_chip)
+    setup3 = SETUP_3.nfft_plan(3)
+    pad3 = padded_grid_size(setup3)
+    assert nfft_window.grid_fits_vmem(pad3, 3, 3)
+    assert not nfft_window.grid_fits_vmem(pad3, 3, 4)
+    assert nfft_window.channels_per_call(pad3, 3, 8) == 3
+    with pytest.raises(Exception, match="(?i)vmem|exceed memory"):
+        _compile("gather", 100_000, setup3, 8, one_chip)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert resolve_backend("auto", plan, 1, jnp.float32) == "xla"
+    assert resolve_backend("auto", huge, 1, jnp.float32) == "xla"
+    assert resolve_backend("auto", setup3, 8, jnp.float32) == "pallas"
+    for channels in (1, 4):
+        assert resolve_backend("auto", SETUP_2.nfft_plan(3), channels,
+                               jnp.float32) == "pallas"
     for n, fits in SHAPES.values():
         assert resolve_backend("auto", fits, 4, jnp.float32) == "pallas"
     # Mosaic has no 64-bit floats: float64 data takes the XLA path on TPU
     assert resolve_backend("auto", FIG5.nfft_plan(3), 1,
                            jnp.float64) == "xla"
+
+
+def _channels_on_lanes_fits(padded_size: int, d: int, channels: int) -> bool:
+    """The rule of the channels-on-lanes layout the kernels held before the
+    lane-dense one: a ``(P,)*d + (C,)`` block, channels padded to 128
+    lanes, within the same VMEM budget."""
+    return (nfft_window.vmem_bytes((padded_size,) * d + (channels,))
+            <= nfft_window.VMEM_GRID_BUDGET)
+
+
+def test_every_grid_the_kernels_held_still_takes_them(monkeypatch):
+    """No grid that took the Pallas kernels under the channels-on-lanes
+    layout goes to XLA under the lane-dense one: every (d, P, C) of the
+    kernel-compile plans, C up to 256, where the old block fitted, still
+    resolves to "pallas", in channel chunks whose blocks fit."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    held = 0
+    for name in ("fig5_d3", "setup2_d2", "setup3_d1"):
+        plan = SHAPES[name][1]
+        pad = padded_grid_size(plan)
+        for channels in range(1, 257):
+            if not _channels_on_lanes_fits(pad, plan.d, channels):
+                continue
+            held += 1
+            assert resolve_backend("auto", plan, channels,
+                                   jnp.float32) == "pallas", (name, channels)
+            width = nfft_window.channels_per_call(pad, plan.d, channels)
+            assert 1 <= width <= channels
+            assert nfft_window.grid_fits_vmem(pad, plan.d, width)
+    assert held == 3 * 256  # the old block held all three up to C = 256
+    # fig5's grid is the one that needs chunks: 53 channels per call
+    # (0.74 MB of grid and 0.52 MB of gather rows each)
+    assert nfft_window.channels_per_call(
+        padded_grid_size(FIG5.nfft_plan(3)), 3, 256) == 52  # 4 x 52 + 48
 
 
 _METADATA = re.compile(r", metadata=\{[^}]*\}")
