@@ -10,8 +10,10 @@ child that needs it):
           eps_B=1/8, through ``make_normalized_adjacency`` and
           ``spectral_clustering`` (k=4);
 * spiral  paper Fig. 3 data, d=3, SETUP_2, n=100,000: ``eigsh`` k=10,
-          single-vector and ``block_size=4``;
-* ssl     crescent-fullmoon, d=2, n=100,000: one kernel-SSL CG solve.
+          single-vector and ``block_size=4``.
+
+The crescent kernel-SSL CG solve (d=2) is the benchmark's
+``crescent.ssl_cg`` cell (``bench/``).
 
 Each phase is checked against the float32 direct product
 (``direct_matvec_tiled``, O(n^2) work in row blocks; no dense matrix and
@@ -53,16 +55,13 @@ from repro.core import (  # noqa: E402
     make_normalized_adjacency,
 )
 from repro.core.fastsum_exec import resolve_backend  # noqa: E402
-from repro.data.synthetic import (  # noqa: E402
-    crescent_fullmoon, spiral, synthetic_image,
-)
+from repro.data.synthetic import spiral, synthetic_image  # noqa: E402
 from repro.dist.fastsum_dist import (  # noqa: E402
     distributed_matvec_fn, make_sharded_matvec, resolve_pencil_spec,
 )
 from repro.graph.spectral import (  # noqa: E402
     clustering_agreement, spectral_clustering,
 )
-from repro.graph.ssl import kernel_ssl_cg, make_training_vector  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 # Paper Fig. 5 (benchmarks/fig5_segmentation.py): pixels in RGB space.
@@ -70,9 +69,6 @@ FIG5_SIGMA = 90.0
 FIG5_PARAMS = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=1.0 / 8.0)
 # Paper Fig. 3 spiral; the data generator is calibrated to sigma = 3.5.
 SPIRAL_SIGMA = 3.5
-# Paper Figs. 7/8 crescent-fullmoon kernel SSL (benchmarks/fig7_kernel_ssl).
-SSL_SIGMA, SSL_BETA, SSL_SAMPLES = 0.75, 1e3, 5
-SSL_PARAMS = FastsumParams(n_bandwidth=128, m=3, eps_b=0.0)
 
 # The direct reference's row block: (tile, n) kernel values per step.
 DIRECT_TILE = 512
@@ -96,12 +92,6 @@ LIMITS = {
     # rehearsal) dominates.  ~100x margin for the chip's exp/FFT rounding.
     "spiral_matvec_rel": 1e-4,
     "spiral_eig_excess": 1e-4,
-    # N=128 m=3 on crescent data at sigma=0.75: 5.8e-4 in float64.
-    "ssl_matvec_rel": 5e-3,
-    # CG stops at relative residual 1e-4 on the NFFT operator; the true
-    # residual adds beta * ||(A_nfft - A) u|| / ||f||, with beta = 1e3:
-    # 6.6e-4 at n = 5,000 and 2.4e-4 at n = 20,000 in float32.  ~8x margin.
-    "ssl_true_residual": 5e-3,
     # psum/pencil against the one-device matvec: same arithmetic in a
     # different summation order, float32.
     "dist_parity_rel": 1e-5,
@@ -259,39 +249,6 @@ def phase_spiral(n: int = 100_000) -> None:
         eig_check(sub, ref, res, "spiral_eig_excess")
 
 
-def phase_ssl(n: int = 100_000) -> None:
-    phase = "ssl"
-    points, labels = crescent_fullmoon(n, seed=60)
-    pts = jnp.asarray(points, jnp.float32)
-    labs = jnp.asarray(labels)
-    kernel = make_kernel("gaussian", sigma=SSL_SIGMA)
-    f, _ = make_training_vector(labs, SSL_SAMPLES, 2,
-                                key=jax.random.PRNGKey(4), positive_class=1)
-    f = f.astype(jnp.float32)
-    log(phase, n=n, d=2, sigma=SSL_SIGMA, beta=SSL_BETA,
-        params="N=128,m=3", samples_per_class=SSL_SAMPLES)
-
-    def run(pts, f):
-        op = make_normalized_adjacency(kernel, pts, SSL_PARAMS)
-        return op, kernel_ssl_cg(op, f, SSL_BETA, tol=1e-4, maxiter=1000)
-
-    (op, res), kernels = compile_and_run(phase, run, pts, f)
-    check_window_backend(phase, op.fastsum.plan, 1, kernels)
-    misclass = float(jnp.mean((res.u > 0).astype(jnp.int32) != labs))
-    log(phase, cg_iters=int(res.num_iters), converged=bool(res.converged),
-        misclassification=misclass)
-    if not bool(res.converged):
-        raise PhaseFailed(f"{phase}: CG did not converge")
-    ref = DirectReference(kernel, pts)
-    log(phase, direct_reference_s=ref.seconds)
-    matvec_check(phase, op, ref, "ssl_matvec_rel", seed=5)
-    u = res.u
-    true_res = f - (u + SSL_BETA * (u - ref.a(u)))
-    check(phase, "true_residual_rel",
-          float(jnp.linalg.norm(true_res) / jnp.linalg.norm(f)),
-          LIMITS["ssl_true_residual"])
-
-
 def phase_four_chips(n: int = 100_000) -> None:
     """distributed_matvec_fn, psum and pencil, against the one-device
     op.matvec on a 4-device mesh; then one eigsh on the sharded matvec."""
@@ -377,7 +334,7 @@ def main() -> int:
         precision="float32", compile_cache=cache)
 
     phases = ([phase_four_chips] if args.four_chips
-              else [phase_fig5, phase_spiral, phase_ssl])
+              else [phase_fig5, phase_spiral])
     failed = []
     for phase in phases:
         t0 = time.perf_counter()

@@ -10,7 +10,8 @@ Each layer's work runs inside the scope of its name:
 * ``fft_mid``: rfftn -> multiply -> irfftn (or the hook that replaces it);
 * ``gather``: roll, wrap pad, the window gather on either backend, inverse
   permutation;
-* ``krylov``: the Lanczos recurrence, start vector and Ritz extraction;
+* ``krylov``: the Krylov recurrence: Lanczos (start vector, recurrence,
+  Ritz extraction) or CG (recurrence and the exit true-residual pass);
 * ``krylov_orth``: the reorthogonalisation passes and the block QR.
 
 Scopes nest: the degree pass's spread, FFT and gather run inside the build,

@@ -49,6 +49,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 Array = jax.Array
 Matvec = Callable[[Array], Array]
 
@@ -406,10 +408,13 @@ def _cg_plain(matvec: Matvec, b: Array, *, x0: Array | None = None,
               tol: float = 1e-8, maxiter: int = 1000,
               preconditioner: Matvec | None = None,
               stall_window: int = 250) -> SolveResult:
-    """The forward-only CG recurrence (also the implicit VJP's inner solve)."""
-    m = cg_machine(matvec, b, x0=x0, tol=tol, maxiter=maxiter,
-                   preconditioner=preconditioner, stall_window=stall_window)
-    return m.finish(jax.lax.while_loop(m.cond, m.body, m.state))
+    """The forward-only CG recurrence (also the implicit VJP's inner solve),
+    with its exit true-residual pass, in the ``krylov`` scope."""
+    with jax.named_scope(scopes.KRYLOV):
+        m = cg_machine(matvec, b, x0=x0, tol=tol, maxiter=maxiter,
+                       preconditioner=preconditioner,
+                       stall_window=stall_window)
+        return m.finish(jax.lax.while_loop(m.cond, m.body, m.state))
 
 
 class MinresLoopState(NamedTuple):

@@ -4,6 +4,7 @@ from repro.graph.spectral import (  # noqa: F401
 from repro.graph.ssl import (  # noqa: F401
     allen_cahn_ssl, allen_cahn_multiclass, kernel_ssl_cg,
     kernel_ssl_cg_multilayer, kernel_ssl_eig, make_training_vector,
+    predicted_labels, training_matrix,
 )
 from repro.graph.krr import (  # noqa: F401
     krr_fit, krr_fit_grad, krr_fit_sweep, krr_pred_cache_stats, krr_predict,

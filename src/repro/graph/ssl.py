@@ -129,6 +129,32 @@ def allen_cahn_multiclass(adjacency: NormalizedAdjacencyOperator, labels: Array,
     return jnp.argmax(jnp.stack(scores, axis=1), axis=1)
 
 
+def training_matrix(labels: Array, n_classes: int) -> Array:
+    """The right-hand side of kernel SSL from partial labels (Section 6.2.2).
+
+    ``labels`` (n,) holds a node's class in ``0..n_classes-1`` where it is
+    labelled and ``-1`` where it is not.  Two classes give the binary
+    vector f (n,): +1 at the labelled nodes of class 1, -1 at those of class
+    0.  More classes give the one-vs-rest matrix F (n, n_classes): column c
+    holds +1 at the labelled nodes of class c and -1 at the other labelled
+    nodes.  Unlabelled nodes are 0 everywhere.  Traceable under ``jit``.
+    """
+    labelled = labels >= 0
+    if n_classes == 2:
+        return jnp.where(labelled, jnp.where(labels == 1, 1.0, -1.0), 0.0)
+    own = labels[:, None] == jnp.arange(n_classes)
+    return jnp.where(labelled[:, None], jnp.where(own, 1.0, -1.0), 0.0)
+
+
+def predicted_labels(u: Array) -> Array:
+    """Classes of a kernel SSL solution: for a binary u (n,), 1 where
+    u > 0 and 0 elsewhere; for one-vs-rest columns (n, C), the column of
+    the largest value."""
+    if u.ndim == 1:
+        return (u > 0).astype(jnp.int32)
+    return jnp.argmax(u, axis=1).astype(jnp.int32)
+
+
 class KernelSSLResult(NamedTuple):
     u: Array
     num_iters: Array
@@ -137,7 +163,17 @@ class KernelSSLResult(NamedTuple):
 
 def kernel_ssl_cg(adjacency: NormalizedAdjacencyOperator, f: Array, beta: float,
                   *, tol: float = 1e-4, maxiter: int = 1000) -> KernelSSLResult:
-    """Solve (I + beta L_s) u = f with CG + NFFT matvecs (Eq. (6.4))."""
+    """Solve (I + beta L_s) u = f with CG + NFFT matvecs (Eq. (6.4)).
+
+    ``f`` (n,) is one binary right-hand side; ``f`` (n, C) holds C of them,
+    e.g. the one-vs-rest columns of :func:`training_matrix`, solved as C
+    CG recurrences in lockstep with one operator application on all C
+    columns per iteration (:func:`repro.core.solvers.cg`).  ``u`` has
+    ``f``'s shape; ``num_iters`` and ``converged`` are scalars for ``f``
+    (n,) and (C,) per column for ``f`` (n, C).  The solve runs
+    ``max(num_iters)`` applications, then one more for the exit true
+    residual.
+    """
 
     def matvec(x):
         return x + beta * adjacency.laplacian_matvec(x)

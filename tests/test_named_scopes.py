@@ -13,6 +13,7 @@ import pytest
 from repro.core import (FastsumParams, eigsh, fused_pipeline, make_fastsum,
                         make_kernel, make_normalized_adjacency, scopes)
 from repro.graph.spectral import spectral_clustering
+from repro.graph.ssl import kernel_ssl_cg
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _METADATA = re.compile(r", metadata=\{[^}]*\}")
@@ -89,6 +90,47 @@ def test_scopes_change_nothing_but_op_name_metadata(scoped_jobs, job,
     assert stripped.count("\nENTRY ") == 1
     assert "op_name" not in stripped and "\nStackFrames\n" not in stripped
     assert strip_metadata(scoped_jobs[job]) == stripped
+
+
+def ssl_text() -> str:
+    """The benchmark's kernel SSL job at a tiny size: build the normalized
+    adjacency of the points, then ``kernel_ssl_cg`` on four one-vs-rest
+    columns; ``converged`` keeps the exit true-residual pass."""
+    kernel = make_kernel("gaussian", sigma=1.0)
+    params = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125)
+
+    def program(points, f):
+        op = make_normalized_adjacency(kernel, points, params)
+        res = kernel_ssl_cg(op, f, 1e3, tol=1e-4, maxiter=50)
+        return op.degrees, res.u, res.converged
+
+    points = jax.random.normal(jax.random.PRNGKey(0), (96, 3))
+    f = jnp.zeros((96, 4)).at[jnp.arange(8), jnp.arange(8) % 4].set(1.0)
+    return compiled_text(program, points, f)
+
+
+def test_cg_runs_in_the_krylov_scope(monkeypatch):
+    """The CG recurrence and its exit true-residual pass run inside
+    ``krylov``, the operator's scopes nested in it as under Lanczos; with
+    the metadata stripped the program is the one without scopes."""
+    text = ssl_text()
+    assert found_scopes(text) == set(scopes.SCOPES) - {scopes.KRYLOV_ORTH}
+    names = _OP_NAME.findall(text)
+    loop = [n for n in names if n.startswith("jit(program)/krylov/while/")]
+    exit_pass = [n for n in names if n.startswith(
+        "jit(program)/krylov/jit(fused_matvec_tilde)/")]
+    for solve in (loop, exit_pass):
+        assert {scopes.innermost(n) for n in solve} >= {
+            scopes.SPREAD, scopes.FFT_MID, scopes.GATHER}
+    # nothing of the solve runs outside the scope: every loop is the
+    # build's or krylov's
+    assert all(n.split("/")[1] in ("build", "krylov")
+               for n in names if "/while" in n)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = ssl_text()
+    assert found_scopes(bare) == set()
+    assert strip_metadata(text) == strip_metadata(bare)
 
 
 def _operator(n: int = 64, d: int = 2):

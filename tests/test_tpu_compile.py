@@ -28,12 +28,15 @@ from repro.core.nfft import WindowGeometry, padded_grid_size
 from repro.kernels import nfft_window
 
 FIG5 = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=1.0 / 8.0)
+CRESCENT = FastsumParams(n_bandwidth=128, m=3, eps_b=0.0)
 
-# (name, nodes, plan): Fig. 5 segmentation (426,400 RGB pixels), the
-# Fig. 3 spiral at SETUP_2 (d = 3), and the Fig. 3 / SSL accuracy setups
-# at d = 2 and d = 1.
+# (name, nodes, plan): Fig. 5 segmentation (426,400 RGB pixels; its kernel
+# SSL cell runs C = 4), the crescent kernel SSL cell (d = 2, the padded
+# 262^2 grid), the Fig. 3 spiral at SETUP_2 (d = 3), and the Fig. 3 / SSL
+# accuracy setups at d = 2 and d = 1.
 SHAPES = {
     "fig5_d3": (426_400, FIG5.nfft_plan(3)),
+    "crescent_ssl_d2": (100_000, CRESCENT.nfft_plan(2)),
     "spiral_setup2_d3": (100_000, SETUP_2.nfft_plan(3)),
     "setup2_d2": (100_000, SETUP_2.nfft_plan(2)),
     "setup3_d1": (100_000, SETUP_3.nfft_plan(1)),
