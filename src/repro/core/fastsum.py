@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core import fastsum_exec, nfft as nfft_mod, scopes
 from repro.core.kernels import Kernel
+from repro.core.node_order import NodeOrder
 from repro.core.nfft import (
     NfftGeometry, NfftPlan, WindowGeometry, build_geometry,
     build_window_geometry,
@@ -240,6 +241,24 @@ class FastsumOperator:
         """d = W 1 (row sums of the zero-diagonal weight matrix)."""
         ones = jnp.ones((self.n_source,), dtype=jnp.real(self.b_hat).dtype)
         return self.matvec(ones)
+
+    def node_order(self) -> NodeOrder | None:
+        """The Morton order of the window geometry, for a Krylov solve to
+        run in (:mod:`repro.core.node_order`); ``None`` for a rectangular
+        operator or one with no Morton geometry.
+
+        Its ``matvec`` is :meth:`matvec` of this operator on the rows'
+        geometry (:meth:`WindowGeometry.in_row_order`): the same fused
+        pipeline, multiplier and diagonal, with the value permutations
+        left out.
+        """
+        win = self.src_window
+        if self.scaled_tgt is not None or self.multiplier_half is None \
+                or win is None or win.perm is None:
+            return None
+        rows = win.in_row_order()
+        in_rows = dataclasses.replace(self, src_window=rows, tgt_window=rows)
+        return NodeOrder(perm=win.perm, inv=win.inv, matvec=in_rows.matvec)
 
 
 def _scaled_plan(points: Array, params: FastsumParams,
@@ -616,9 +635,13 @@ class NormalizedAdjacencyOperator:
     fastsum: FastsumOperator
     inv_sqrt_deg: Array  # (n,)
     degrees: Array  # (n,)
+    # D^{-1/2} in the fastsum's Morton row order (:meth:`node_order`), made
+    # by the build's degree pass; None when the fastsum has no node order
+    inv_sqrt_deg_rows: Array = None
 
     def tree_flatten(self):
-        return (self.fastsum, self.inv_sqrt_deg, self.degrees), None
+        return (self.fastsum, self.inv_sqrt_deg, self.degrees,
+                self.inv_sqrt_deg_rows), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -640,19 +663,47 @@ class NormalizedAdjacencyOperator:
         scale = inv_deg if x.ndim == 1 else inv_deg[:, None]
         return scale * self.fastsum.matvec(x)
 
+    def node_order(self) -> NodeOrder | None:
+        """The fastsum's Morton node order, with ``A`` applied in it
+        (:mod:`repro.core.node_order`); ``None`` where the build made no
+        ``D^{-1/2}`` in that order."""
+        s = self.inv_sqrt_deg_rows
+        if s is None:
+            return None
+        order = self.fastsum.node_order()
+        fs_rows = order.matvec
+
+        def matvec(x):
+            scale = s if x.ndim == 1 else s[:, None]
+            return scale * fs_rows(scale * x)
+
+        return order._replace(matvec=matvec)
+
+
+def _clamped(deg: Array) -> Array:
+    # Lemma 3.1 requires eps < eta, i.e. the approximation error below the
+    # smallest degree; negative approximate degrees would make D^{-1/2}
+    # imaginary (the classical-Nyström failure mode the paper highlights).
+    return jnp.maximum(deg, jnp.finfo(deg.dtype).tiny)
+
 
 def _normalized_adjacency_from(fs: FastsumOperator) -> NormalizedAdjacencyOperator:
+    node_order = getattr(fs, "node_order", None)
+    order = None if node_order is None else node_order()
     with jax.named_scope(scopes.BUILD):
-        deg = fs.degrees()
-        # Lemma 3.1 requires eps < eta, i.e. the approximation error below
-        # the smallest degree; negative approximate degrees would make
-        # D^{-1/2} imaginary (the classical-Nyström failure mode the paper
-        # highlights).
-        deg = jnp.maximum(deg, jnp.finfo(deg.dtype).tiny)
+        if order is None:
+            deg, rows = _clamped(fs.degrees()), None
+        else:
+            # the degree pass in the rows' order (a vector of ones is the
+            # same in every order), D^{-1/2} kept in it for the solves that
+            # run there, and the degrees mapped back once
+            ones = jnp.ones((fs.n_source,), dtype=jnp.real(fs.b_hat).dtype)
+            deg_rows = _clamped(order.matvec(ones))
+            deg, rows = deg_rows[order.inv], 1.0 / jnp.sqrt(deg_rows)
         inv_sqrt_deg = 1.0 / jnp.sqrt(deg)
     return NormalizedAdjacencyOperator(
-        fastsum=fs, inv_sqrt_deg=inv_sqrt_deg, degrees=deg
-    )
+        fastsum=fs, inv_sqrt_deg=inv_sqrt_deg, degrees=deg,
+        inv_sqrt_deg_rows=rows)
 
 
 def make_normalized_adjacency(

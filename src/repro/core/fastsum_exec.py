@@ -329,11 +329,27 @@ def _channel_chunks(plan: NfftPlan, channels: int) -> list:
             for k in range(0, channels, width)]
 
 
+def _flat_boundary(x: Array) -> Array:
+    """Node values ``(n, C)`` passed through a flat vector that XLA keeps.
+
+    The kernels read and write node values as rows of an ``(n, C)`` array,
+    which the TPU lays out with the channels on the 128 lanes: 128 times
+    the bytes at one channel.  Left to itself, XLA gives that layout to
+    whatever makes or takes the values, a Krylov solve's vectors over its
+    whole loop, rather than convert once per call.  A flat vector behind an
+    optimization barrier has a dense layout only, so the conversion happens
+    here, once per call.
+    """
+    n, c = x.shape
+    return jax.lax.optimization_barrier(x.reshape(n * c)).reshape(n, c)
+
+
 def _pallas_spread(plan: NfftPlan, geometry: WindowGeometry,
                    xs: Array) -> Array:
     """The Pallas spread, channel chunk by chunk, in the engine's
     ``(P,)*d + (C,)`` layout."""
     pad_n = padded_grid_size(plan)
+    xs = _flat_boundary(xs)
     blocks = [kernel_ops.window_spread(xs[:, cs], geometry.base,
                                        geometry.weights, padded_size=pad_n)
               for cs in _channel_chunks(plan, xs.shape[-1])]
@@ -348,7 +364,7 @@ def _pallas_gather(plan: NfftPlan, geometry: WindowGeometry,
     outs = [kernel_ops.window_gather(block[cs], geometry.base,
                                      geometry.weights)
             for cs in _channel_chunks(plan, gpad.shape[-1])]
-    return jnp.concatenate(outs, axis=1)
+    return _flat_boundary(jnp.concatenate(outs, axis=1))
 
 
 def window_spread(plan: NfftPlan, geometry: WindowGeometry, x: Array, *,
@@ -385,7 +401,8 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
 
     Exact transpose of :func:`window_spread` (same geometry, same weights):
     wrap-pad the grid, stream one (taps,)^d window gather per node on the
-    selected backend, then restore node order.
+    selected backend, then restore node order (none to restore where the
+    geometry has no ``perm``).
     """
     d, taps = plan.d, plan.taps
     with jax.named_scope(scopes.GATHER):
@@ -397,13 +414,13 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
             out = _xla_gather(plan, geometry, gpad)
         if geometry.perm is None:
             return out
-        # restore node order via the inverse permutation as a row *take*:
-        # the equivalent multi-channel row scatter costs ~10x more on XLA
-        # CPU, and the (n,) int scatter building the inverse is
-        # single-channel (cheap)
-        inv = jnp.zeros_like(geometry.perm).at[geometry.perm].set(
-            jnp.arange(out.shape[0], dtype=geometry.perm.dtype))
-        return out[inv]
+        if geometry.inv is None:
+            raise ValueError("a geometry with a permutation needs its "
+                             "inverse (build_window_geometry makes both)")
+        # restore node order with the geometry's inverse permutation as a
+        # row *take*: the equivalent multi-channel row scatter costs ~10x
+        # more on XLA CPU
+        return out[geometry.inv]
 
 
 # ---------------------------------------------------------------------------

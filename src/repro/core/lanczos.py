@@ -30,7 +30,7 @@ from typing import Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import scopes
+from repro.core import node_order, scopes
 
 Array = jax.Array
 Matvec = Callable[[Array], Array]
@@ -418,15 +418,30 @@ def eigsh(matvec: Matvec, n: int, k: int, *, num_iters: int | None = None,
     batches, so the number of matvec invocations drops by ~``block_size``
     (the fused fastsum engine executes a block in one spread/FFT/gather
     pass).  The matvec callable must accept (n, C) input in that case.
+
+    Handed the bound ``matvec`` of an operator with a node order (a fastsum
+    adjacency, :mod:`repro.core.node_order`), the recurrence runs in that
+    order: the start vector is permuted in once and the Ritz vectors out
+    once, and no application permutes.  The results are the same up to
+    the order of floating-point sums.
     """
     with jax.named_scope(scopes.KRYLOV):
         setup = eigsh_setup(n, k, num_iters=num_iters, which=which, key=key,
                             dtype=dtype, v0=v0, block_size=block_size)
+        order = node_order.of(matvec)
+        if order is not None:
+            matvec = order.matvec
+            setup = setup._replace(v0=node_order.rows(order, setup.v0)[0])
         if setup.num_blocks:
             res = block_lanczos(matvec, setup.v0, setup.num_blocks)
-            return ritz_from_block(res, setup, n)
-        res = lanczos(matvec, setup.v0, setup.num_iters)
-        return ritz_from_lanczos(res, setup)
+            res = ritz_from_block(res, setup, n)
+        else:
+            res = ritz_from_lanczos(lanczos(matvec, setup.v0,
+                                            setup.num_iters), setup)
+        if order is None:
+            return res
+        return res._replace(
+            eigenvectors=node_order.nodes(order, res.eigenvectors))
 
 
 def eigsh_smallest_laplacian(adjacency_matvec: Matvec, n: int, k: int,

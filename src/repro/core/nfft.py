@@ -270,15 +270,19 @@ class WindowGeometry:
     weights: (n, d, taps) — per-dimension window values.
     perm: (n,) int32 — rows are Morton-sorted; row ``r`` is node ``perm[r]``.
       ``None`` when the rows already are in node order (the per-shard
-      geometry of the distributed matvec, whose caller pre-permutes).
+      geometry of the distributed matvec, whose caller pre-permutes, and
+      :meth:`in_row_order`).
+    inv: (n,) int32 — the inverse permutation: node ``i`` is row
+      ``inv[i]``.  Built once with ``perm``; ``None`` where ``perm`` is.
     """
 
     base: Array
     weights: Array
     perm: Array
+    inv: Array = None
 
     def tree_flatten(self):
-        return (self.base, self.weights, self.perm), None
+        return (self.base, self.weights, self.perm, self.inv), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -287,6 +291,13 @@ class WindowGeometry:
     @property
     def n_nodes(self) -> int:
         return self.base.shape[0]
+
+    def in_row_order(self) -> "WindowGeometry":
+        """The same rows addressed in their own (Morton) order: a spread
+        takes node values already in row order, and a gather returns them
+        so, with no permutation either way."""
+        return WindowGeometry(base=self.base, weights=self.weights,
+                              perm=None)
 
 
 def window_shift(plan: NfftPlan) -> int:
@@ -323,14 +334,18 @@ def build_window_geometry(plan: NfftPlan, nodes: Array, *,
     """Separable (fused-engine) window geometry for nodes in [-1/2, 1/2)^d."""
     n, d = nodes.shape
     assert d == plan.d, (d, plan.d)
-    perm = jnp.arange(n, dtype=jnp.int32)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    perm = inv = iota
     if sort:
         cells = (jnp.floor(nodes * plan.grid_size).astype(jnp.int32)
                  - plan.m + window_shift(plan))  # the rows' base corners
         perm, nodes = _morton_sort(cells, plan.grid_size, nodes)
+        # the one index scatter of the permutation, here and not in every
+        # gather that restores node order
+        inv = jnp.zeros_like(perm).at[perm].set(iota)
     base, _, w_d = _window_taps_1d(plan, nodes)
     return WindowGeometry(base=base + window_shift(plan), weights=w_d,
-                          perm=perm)
+                          perm=perm, inv=inv)
 
 
 def _window_fourier_1d_np(plan: NfftPlan, k: np.ndarray) -> np.ndarray:

@@ -49,7 +49,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import scopes
+from repro.core import node_order, scopes
 
 Array = jax.Array
 Matvec = Callable[[Array], Array]
@@ -199,7 +199,22 @@ def cg(matvec: Matvec, b: Array, *, x0: Array | None = None,
     gradients.  ``implicit_diff=False`` restores the plain forward-only
     recurrence (matvecs that refuse abstract tracing also fall back to it
     automatically).
+
+    Handed the bound ``matvec`` of an operator with a node order (a fastsum
+    adjacency, :mod:`repro.core.node_order`) and no preconditioner, the
+    solve runs in that order: ``b`` and ``x0`` are permuted in once and
+    ``x`` out once, and no application permutes.  The results are the same
+    up to the order of floating-point sums.  A preconditioner works in the
+    caller's order, so with one the solve does too.
     """
+    order = None if preconditioner is not None else node_order.of(matvec)
+    if order is not None:
+        with jax.named_scope(scopes.KRYLOV):
+            b, x0 = node_order.rows(order, b, x0)
+        sol = cg(order.matvec, b, x0=x0, tol=tol, maxiter=maxiter,
+                 stall_window=stall_window, implicit_diff=implicit_diff)
+        with jax.named_scope(scopes.KRYLOV):
+            return sol._replace(x=node_order.nodes(order, sol.x))
     if implicit_diff:
         conv = _try_closure_convert(matvec, b, preconditioner)
         if conv is not None:

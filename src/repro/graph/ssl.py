@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import node_order, scopes
 from repro.core.fastsum import (
     FastsumParams, NormalizedAdjacencyOperator,
     make_normalized_adjacency_mixture,
@@ -173,13 +174,27 @@ def kernel_ssl_cg(adjacency: NormalizedAdjacencyOperator, f: Array, beta: float,
     (n,) and (C,) per column for ``f`` (n, C).  The solve runs
     ``max(num_iters)`` applications, then one more for the exit true
     residual.
+
+    Where the adjacency has a node order (:mod:`repro.core.node_order`),
+    the system is composed from its application in that order, so CG runs
+    there: ``f`` is permuted in once and ``u`` out once.
     """
+    order = node_order.of(adjacency.matvec)
+    adjacency_matvec = adjacency.matvec if order is None else order.matvec
 
     def matvec(x):
-        return x + beta * adjacency.laplacian_matvec(x)
+        return x + beta * (x - adjacency_matvec(x))
 
-    sol = cg(matvec, f, tol=tol, maxiter=maxiter)
-    return KernelSSLResult(u=sol.x, num_iters=sol.num_iters,
+    if order is None:
+        sol = cg(matvec, f, tol=tol, maxiter=maxiter)
+        u = sol.x
+    else:
+        with jax.named_scope(scopes.KRYLOV):
+            (f_rows,) = node_order.rows(order, f)
+        sol = cg(matvec, f_rows, tol=tol, maxiter=maxiter)
+        with jax.named_scope(scopes.KRYLOV):
+            u = node_order.nodes(order, sol.x)
+    return KernelSSLResult(u=u, num_iters=sol.num_iters,
                            converged=sol.converged)
 
 

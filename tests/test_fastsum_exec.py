@@ -342,3 +342,21 @@ def test_explicit_pallas_lowering_failure_still_raises(monkeypatch):
     with pytest.raises(RuntimeError, match="forced Mosaic"):
         fastsum_exec.window_spread(fs.plan, fs.src_window, x,
                                    backend="pallas")
+
+
+@pytest.mark.parametrize("d", [1, 2, 3])
+def test_window_geometry_stores_the_inverse_permutation(d):
+    """The geometry carries the inverse of its Morton permutation, built
+    once with it: ``inv == argsort(perm)``."""
+    plan = SETUP_1.nfft_plan(d)
+    rng = np.random.default_rng(30 + d)
+    nodes = jnp.asarray(rng.uniform(-0.25, 0.25, size=(500, d)))
+    win = build_window_geometry(plan, nodes)
+    perm = np.asarray(win.perm)
+    np.testing.assert_array_equal(np.asarray(win.inv), np.argsort(perm))
+    assert not np.array_equal(perm, np.arange(500))  # a real permutation
+    unsorted = build_window_geometry(plan, nodes, sort=False)
+    np.testing.assert_array_equal(np.asarray(unsorted.inv), np.arange(500))
+    rows = win.in_row_order()
+    assert rows.perm is None and rows.inv is None
+    assert rows.base is win.base and rows.weights is win.weights

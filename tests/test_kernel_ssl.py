@@ -99,3 +99,44 @@ def test_training_matrix_and_predicted_labels():
         np.asarray(predicted_labels(jnp.asarray([[0.1, 0.4, -1.0],
                                                   [0.9, 0.2, 0.3]]))),
         [1, 0])
+
+
+def small_crescent():
+    points, classes = crescent_fullmoon(600, seed=5)
+    return points, classes, 2, 0.75, FastsumParams(n_bandwidth=64, m=3,
+                                                   eps_b=0.0)
+
+
+def small_image():
+    img, lab = synthetic_image(20, 30, seed=5)
+    return (img.reshape(-1, 3), lab.reshape(-1), 4, 90.0,
+            FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125))
+
+
+@pytest.mark.parametrize("problem", [small_crescent, small_image],
+                         ids=["d2-one-column", "d3-four-columns"])
+def test_kernel_ssl_cg_in_node_order_matches_the_callers_order(problem):
+    """``kernel_ssl_cg`` composes its system from the adjacency's Morton
+    order application, so its CG runs there (``f`` in and ``u`` out once);
+    plain ``cg`` on the same system as a bare callable runs in the
+    caller's order.  Same iterations per column, and the same ``u`` to the
+    solver's tolerance."""
+    from repro.core import cg
+
+    points, classes, n_classes, sigma, params = problem()
+    op = make_normalized_adjacency(make_kernel("gaussian", sigma=sigma),
+                                   jnp.asarray(points), params)
+    f = training_matrix(jnp.asarray(given_labels(classes, 5, n_classes, 2)),
+                        n_classes)
+    tol = 1e-8
+    got = kernel_ssl_cg(op, f, BETA, tol=tol, maxiter=1000)
+    want = cg(lambda x: x + BETA * op.laplacian_matvec(x), f, tol=tol,
+              maxiter=1000)
+    np.testing.assert_array_equal(np.asarray(got.num_iters),
+                                  np.asarray(want.num_iters))
+    assert np.all(np.asarray(got.converged))
+    # both iterates meet the tolerance on the residual; cond(I + beta L_s)
+    # <= 1 + 2 beta bounds their distance
+    err = np.linalg.norm(np.asarray(got.u - want.x), axis=0)
+    bound = 2 * (1 + 2 * BETA) * tol * np.linalg.norm(np.asarray(f), axis=0)
+    assert np.all(err <= bound), (err, bound)

@@ -172,8 +172,10 @@ _LANE_DENSE_PLANS = {
 
 def _engine_geometry(plan, n):
     base, w = _sep_geom(n, plan.d, plan.taps, padded_grid_size(plan))
+    perm = RNG.permutation(n)
     return WindowGeometry(base=base, weights=w,
-                          perm=jnp.asarray(RNG.permutation(n), jnp.int32))
+                          perm=jnp.asarray(perm, jnp.int32),
+                          inv=jnp.asarray(np.argsort(perm), jnp.int32))
 
 
 def _engine_parity(plan, n, c):

@@ -93,3 +93,40 @@ def test_block_eigsh_v0_wider_than_shrunk_block():
     ref = np.sort(np.linalg.eigvalsh(np.asarray(a)))[::-1][:k]
     np.testing.assert_allclose(np.asarray(res.eigenvalues), ref,
                                rtol=1e-8, atol=1e-8)
+
+
+def _adjacency(d: int, dtype):
+    """A fastsum adjacency of 400 uniform points: its ``matvec`` carries
+    the Morton node order (``repro.core.node_order``)."""
+    from repro.core import FastsumParams
+
+    pts = jax.random.uniform(jax.random.PRNGKey(d), (400, d), dtype=dtype)
+    return make_normalized_adjacency(make_kernel("gaussian", sigma=0.3), pts,
+                                     FastsumParams(n_bandwidth=16, m=3))
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["float64", "float32"])
+@pytest.mark.parametrize("block", [1, 4])
+@pytest.mark.parametrize("d", [2, 3])
+def test_eigsh_in_node_order_matches_the_callers_order(d, block, x64):
+    """``eigsh`` on ``op.matvec`` runs in the operator's Morton order; on a
+    bare callable of the same operator it runs in the caller's.  The
+    results agree up to the order of floating-point sums."""
+    with jax.enable_x64(x64):
+        dtype = jnp.float64 if x64 else jnp.float32
+        op = _adjacency(d, dtype)
+        kw = dict(key=jax.random.PRNGKey(1), block_size=block, dtype=dtype)
+        got = eigsh(op.matvec, op.n, 4, **kw)
+        want = eigsh(lambda x: op.matvec(x), op.n, 4, **kw)
+    assert got.num_matvecs == want.num_matvecs
+    np.testing.assert_allclose(np.asarray(got.eigenvalues),
+                               np.asarray(want.eigenvalues), rtol=1e-6)
+    # each eigenvector up to its sign
+    sign = np.sign(np.sum(np.asarray(got.eigenvectors)
+                          * np.asarray(want.eigenvectors), axis=0))
+    np.testing.assert_allclose(np.asarray(got.eigenvectors) * sign,
+                               np.asarray(want.eigenvectors), rtol=0,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.residual_bounds),
+                               np.asarray(want.residual_bounds), rtol=0,
+                               atol=1e-5)

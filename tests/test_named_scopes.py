@@ -8,6 +8,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import (FastsumParams, eigsh, fused_pipeline, make_fastsum,
@@ -198,3 +199,134 @@ def test_the_custom_vjp_backward_carries_the_operator_scopes():
 ])
 def test_innermost(op_name, scope):
     assert scopes.innermost(op_name) == scope
+
+
+# --------------------------------------------------------------------------
+# The ``node_order`` scope: a Krylov solve on an operator with a node order
+# permutes its inputs in once and its results out once, outside the loop,
+# and no application inside the loop permutes node values.
+
+N_NODES = 96
+
+
+def _eqns(jaxpr, in_loop=False, path=""):
+    """Every equation of a jaxpr and of the jaxprs inside it:
+    ``(eqn, inside a loop, name stack)``."""
+    for eqn in jaxpr.eqns:
+        here = path + "/" + str(eqn.source_info.name_stack)
+        yield eqn, in_loop, here
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else [param]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(
+                        inner,
+                        in_loop or eqn.primitive.name in ("while", "scan"),
+                        here)
+
+
+def node_permutations(fn, *args):
+    """The row gathers of node vectors ((n,) or (n, C) by (n, 1) indices)
+    and the index scatters over n nodes in ``fn``'s program, as
+    ``(primitive, inside a loop, under node_order)``."""
+    out = []
+    for eqn, in_loop, stack in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        shapes = [getattr(v.aval, "shape", ()) for v in eqn.invars]
+        name = eqn.primitive.name
+        if name == "gather" and shapes[0][:1] == (N_NODES,) \
+                and shapes[1] == (N_NODES, 1):
+            out.append((name, in_loop, scopes.NODE_ORDER in stack))
+        elif name == "scatter" and shapes[0] == (N_NODES,) \
+                and shapes[2] == (N_NODES,):
+            out.append((name, in_loop, scopes.NODE_ORDER in stack))
+    return out
+
+
+def _adjacency(points):
+    return make_normalized_adjacency(
+        make_kernel("gaussian", sigma=1.0), points,
+        FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125))
+
+
+def _points():
+    return jax.random.normal(jax.random.PRNGKey(0), (N_NODES, 3))
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_eigsh_permutes_twice_outside_its_loop(block):
+    """The start vector in and the Ritz vectors out, under ``node_order``;
+    the build's inverse scatter and degrees out; nothing in the loop."""
+    def program(points, key):
+        op = _adjacency(points)
+        return eigsh(op.matvec, op.n, 4, key=key, block_size=block,
+                     dtype=op.inv_sqrt_deg.dtype).eigenvectors
+
+    perms = node_permutations(program, _points(), jax.random.PRNGKey(1))
+    assert not any(in_loop for _, in_loop, _ in perms), perms
+    assert [p for p in perms if p[2]] == [("gather", False, True)] * 2
+    # the build: the geometry's one inverse scatter, the degrees mapped back
+    assert sorted(p for p in perms if not p[2]) == [
+        ("gather", False, False), ("scatter", False, False)]
+
+
+@pytest.mark.parametrize("solve", ["cg", "kernel_ssl_cg"])
+def test_cg_permutes_twice_outside_its_loop(solve):
+    """``b`` in and ``x`` out under ``node_order``, nothing in the loop:
+    ``cg`` on an operator's bound ``matvec``, and ``kernel_ssl_cg``."""
+    from repro.core import cg
+
+    def program(points, f):
+        op = _adjacency(points)
+        if solve == "cg":
+            return cg(op.matvec, f, tol=1e-6, maxiter=20).x
+        return kernel_ssl_cg(op, f, 1e3, tol=1e-6, maxiter=20).u
+
+    perms = node_permutations(program, _points(), jnp.ones(N_NODES))
+    assert not any(in_loop for _, in_loop, _ in perms), perms
+    assert [p for p in perms if p[2]] == [("gather", False, True)] * 2
+
+
+def test_a_bare_callable_keeps_the_callers_order():
+    """A bare callable has no node order: every application in the loop
+    permutes in and out (two row gathers), and no ``node_order`` scope."""
+    def program(points, key):
+        op = _adjacency(points)
+        return eigsh(lambda x: op.matvec(x), op.n, 4, key=key,
+                     dtype=op.inv_sqrt_deg.dtype).eigenvectors
+
+    perms = node_permutations(program, _points(), jax.random.PRNGKey(1))
+    assert not any(under for _, _, under in perms)
+    assert [p for p in perms if p[1]] == [("gather", True, False)] * 2
+
+
+def test_a_direct_matvec_builds_no_inverse():
+    """Outside a solve ``matvec`` keeps the caller's order: one gather in,
+    one out, and no index scatter per call."""
+    op = _adjacency(_points())
+    perms = node_permutations(op.matvec, jnp.ones(N_NODES))
+    assert perms == [("gather", False, False)] * 2
+
+
+def test_only_square_operators_with_a_morton_geometry_have_a_node_order():
+    from repro.core import make_fastsum_bank, node_order
+
+    points = _points()
+    kernel = make_kernel("gaussian", sigma=1.0)
+    params = FastsumParams(n_bandwidth=16, m=2, p=2, eps_b=0.125)
+    op = _adjacency(points)
+    assert node_order.of(op.matvec) is not None
+    assert node_order.of(op.fastsum.matvec) is not None
+    assert node_order.of(op.laplacian_matvec) is None
+    assert node_order.of(lambda x: op.matvec(x)) is None
+    rect = make_fastsum(kernel, points, params, target_points=points[:10])
+    assert rect.node_order() is None
+    assert node_order.of(rect.matvec_tilde) is None
+    bank = make_fastsum_bank([kernel], points, params)
+    assert node_order.of(bank.matvec) is None
+    order = node_order.of(op.matvec)
+    x = jax.random.normal(jax.random.PRNGKey(3), (N_NODES, 2))
+    # the order's application is the operator's, in Morton rows
+    np.testing.assert_allclose(
+        np.asarray(order.matvec(x[order.perm])[order.inv]),
+        np.asarray(op.matvec(x)), rtol=0, atol=1e-12)

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import cg, minres
 
@@ -245,3 +246,97 @@ def test_healthy_solves_report_clean_health():
         h = sol.health
         assert not np.any(np.asarray(h.any_fault))
         assert np.all(np.asarray(h.breakdown_iter) == -1)
+
+
+class _ShiftedAdjacency:
+    """``I + beta (I - A)`` on a fastsum adjacency: SPD, and with ``A``'s
+    node order (``repro.core.node_order``), so ``cg`` on its ``matvec``
+    runs in Morton order."""
+
+    def __init__(self, op, beta):
+        self.op, self.beta = op, beta
+
+    def matvec(self, x):
+        return x + self.beta * (x - self.op.matvec(x))
+
+    def node_order(self):
+        order = self.op.node_order()
+        return order._replace(
+            matvec=lambda x: x + self.beta * (x - order.matvec(x)))
+
+
+def _shifted(d: int, beta=10.0):
+    from repro.core import (FastsumParams, make_kernel,
+                            make_normalized_adjacency)
+
+    pts = jax.random.uniform(jax.random.PRNGKey(10 + d), (400, d))
+    op = make_normalized_adjacency(make_kernel("gaussian", sigma=0.3), pts,
+                                   FastsumParams(n_bandwidth=16, m=3))
+    return _ShiftedAdjacency(op, beta)
+
+
+@pytest.mark.parametrize("columns,with_x0", [(None, False), (3, True)],
+                         ids=["one-column", "three-columns-x0"])
+@pytest.mark.parametrize("implicit_diff", [False, True],
+                         ids=["plain", "implicit-diff"])
+@pytest.mark.parametrize("d", [2, 3])
+def test_cg_in_node_order_matches_the_callers_order(d, implicit_diff,
+                                                    columns, with_x0):
+    """``cg`` on the bound ``matvec`` of an operator with a node order runs
+    in that order, ``b``/``x0`` in and ``x`` out once; on a bare callable
+    it runs in the caller's.  Same iterations, same solution to the
+    tolerance."""
+    system = _shifted(d)
+    rng = np.random.default_rng(d)
+    shape = (400,) if columns is None else (400, columns)
+    b = jnp.asarray(rng.normal(size=shape))
+    x0 = jnp.asarray(0.1 * rng.normal(size=shape)) if with_x0 else None
+    kw = dict(x0=x0, tol=1e-8, maxiter=200, implicit_diff=implicit_diff)
+    got = cg(system.matvec, b, **kw)
+    want = cg(lambda x: system.matvec(x), b, **kw)
+    np.testing.assert_array_equal(np.asarray(got.num_iters),
+                                  np.asarray(want.num_iters))
+    assert np.all(np.asarray(got.converged))
+    np.testing.assert_allclose(np.asarray(got.residual_norm),
+                               np.asarray(want.residual_norm), rtol=1e-3)
+    err = np.linalg.norm(np.asarray(got.x - want.x), axis=0)
+    assert np.all(err <= 1e-8 * np.linalg.norm(np.asarray(b), axis=0)), err
+
+
+def test_cg_with_a_preconditioner_keeps_the_callers_order():
+    """A preconditioner works in the caller's order, so the solve does
+    too, and agrees with the unpreconditioned solve in node order."""
+    system = _shifted(2)
+    b = jnp.asarray(np.random.default_rng(5).normal(size=400))
+    got = cg(system.matvec, b, tol=1e-10, maxiter=200,
+             preconditioner=lambda r: r / (1.0 + system.beta))
+    want = cg(system.matvec, b, tol=1e-10, maxiter=200)
+    assert bool(got.converged) and bool(want.converged)
+    np.testing.assert_allclose(np.asarray(got.x), np.asarray(want.x),
+                               rtol=0, atol=1e-8)
+
+
+@pytest.mark.parametrize("d", [2, 3])
+def test_implicit_diff_cg_in_node_order_gradcheck(d):
+    """Gradients through an implicit-diff CG that runs in node order: with
+    respect to ``b`` they equal the caller's-order solve's, and with
+    respect to ``beta`` (closed over by the operator) central differences."""
+    op = _shifted(d).op
+    rng = np.random.default_rng(40 + d)
+    b = jnp.asarray(rng.normal(size=400))
+    w = jnp.asarray(rng.normal(size=400))
+
+    def loss(beta, rhs, bare=False):
+        system = _ShiftedAdjacency(op, beta)
+        mv = (lambda x: system.matvec(x)) if bare else system.matvec
+        return jnp.vdot(w, cg(mv, rhs, tol=1e-12, maxiter=400).x)
+
+    g_b = jax.grad(loss, argnums=1)(10.0, b)
+    g_b_bare = jax.grad(loss, argnums=1)(10.0, b, bare=True)
+    np.testing.assert_allclose(np.asarray(g_b), np.asarray(g_b_bare),
+                               rtol=0, atol=1e-9 * float(jnp.max(
+                                   jnp.abs(g_b_bare))))
+    g_beta = float(jax.grad(loss)(10.0, b))
+    h = 1e-4
+    fd = float(loss(10.0 + h, b) - loss(10.0 - h, b)) / (2 * h)
+    assert abs(g_beta - fd) <= 1e-6 * max(abs(fd), 1e-12), (g_beta, fd)

@@ -189,7 +189,8 @@ def _pallas_matvec_text(sharding, n: int = 4096) -> str:
     grid = plan.grid_size
     geometry = WindowGeometry(base=spec((n, 3), jnp.int32),
                               weights=spec((n, 3, plan.taps), jnp.float32),
-                              perm=spec((n,), jnp.int32))
+                              perm=spec((n,), jnp.int32),
+                              inv=spec((n,), jnp.int32))
     jax.clear_caches()  # trace anew: a cached jaxpr keeps its op names
     return jax.jit(lambda m, g, x: fused_pipeline(
         plan, m, g, g, x, backend="pallas")).lower(
@@ -217,3 +218,36 @@ def test_scopes_leave_the_compiled_pallas_matvec_unchanged(one_chip,
     assert op_metadata_stripped(scoped) != op_metadata_stripped(bare)
     assert _without_metadata(scoped) == _without_metadata(bare)
     assert _without_metadata(bare).count("\nENTRY ") == 1
+
+
+def test_krylov_vectors_keep_a_dense_layout_around_the_kernels(one_chip,
+                                                                monkeypatch):
+    """A CG solve in the operator's node order hands its vectors to the
+    Pallas kernels with no row gather between them.  The kernels take node
+    values as rows, one value per 128 lanes at one channel
+    (``{1,0:T(8,128)}``); the solve's loop-carried (n, 1) vectors keep a
+    dense layout all the same, as they did behind the gathers."""
+    import repro.graph.ssl as ssl
+    from repro.core import make_kernel, make_normalized_adjacency
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 4096
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def program(points, f):
+        op = make_normalized_adjacency(make_kernel("gaussian", sigma=0.75),
+                                       points, CRESCENT)
+        return ssl.kernel_ssl_cg(op, f, 1e3, tol=1e-4, maxiter=100).u
+
+    with jax.enable_x64(False):
+        jax.clear_caches()
+        text = jax.jit(program).lower(spec((n, 2), jnp.float32),
+                                      spec((n,), jnp.float32)
+                                      ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    carried = re.findall(
+        r"= f32\[%d,1\]\{([^}]*)\} get-tuple-element" % n, text)
+    assert carried and all(layout.startswith("0,1") for layout in carried), \
+        carried
