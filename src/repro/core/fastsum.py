@@ -120,9 +120,9 @@ class FastsumOperator:
     kernel_at_zero: Array  # K(0) for the *rescaled* kernel, already corrected
     # Fused-engine state (plan-once): combined spectral multiplier on the
     # oversampled half-spectrum + separable Morton-sorted window geometry.
-    multiplier_half: Array = None
-    src_window: WindowGeometry = None
-    tgt_window: WindowGeometry = None
+    multiplier_half: Array
+    src_window: WindowGeometry
+    tgt_window: WindowGeometry
     # Re-spectralization state: the admissible-ball scale factor and the
     # accuracy parameters the operator was planned with.  Geometry (points,
     # rho, Morton windows) is fixed plan-time data with zero cotangents; the
@@ -202,10 +202,6 @@ class FastsumOperator:
         ``backend`` selects the window-step backend ("auto"/"xla"/"pallas",
         see :func:`repro.core.fastsum_exec.resolve_backend`).
         """
-        if self.multiplier_half is None:  # legacy operators built by hand
-            # validate even when unused
-            fastsum_exec.resolve_backend(backend, self.plan, 1, x.dtype)
-            return self.matvec_tilde_reference(x)
         f = fastsum_exec.fused_matvec_tilde(
             self.plan, self.multiplier_half, self.src_window,
             self.tgt_window, x, backend=backend)
@@ -253,8 +249,7 @@ class FastsumOperator:
         left out.
         """
         win = self.src_window
-        if self.scaled_tgt is not None or self.multiplier_half is None \
-                or win is None or win.perm is None:
+        if self.scaled_tgt is not None or win.perm is None:
             return None
         rows = win.in_row_order()
         in_rows = dataclasses.replace(self, src_window=rows, tgt_window=rows)

@@ -203,14 +203,13 @@ def _xla_spread(plan: NfftPlan, geometry: WindowGeometry, xs: Array) -> Array:
     return jax.lax.fori_loop(0, num_tiles, body, gpad)
 
 
-def _xla_gather_windowed(plan: NfftPlan, geometry: WindowGeometry,
-                         gpad: Array) -> Array:
+def _xla_gather(plan: NfftPlan, geometry: WindowGeometry,
+                gpad: Array) -> Array:
     """Streaming tiled whole-window gather (transpose of :func:`_xla_spread`).
 
-    The fast single-channel body: one `lax.gather` of (taps,)^d + (C,)
-    window slices per node tile.  XLA CPU expands gathers to per-element
-    loops, and this slice shape hits the cheap expansion only for C = 1 —
-    multi-channel inputs route through :func:`_xla_gather` instead.
+    One `lax.gather` of (taps,)^d + (C,) window slices per node tile,
+    contracted with the tile's weight cube; node values come out (n, C) in
+    row (Morton) order.
     """
     d, taps = plan.d, plan.taps
     c = gpad.shape[-1]
@@ -239,85 +238,6 @@ def _xla_gather_windowed(plan: NfftPlan, geometry: WindowGeometry,
     if num_tiles == 1:
         return body(0, acc)[:n]
     return jax.lax.fori_loop(0, num_tiles, body, acc)[:n]
-
-
-# Multi-channel gather strategy thresholds, tuned empirically on CPU (see
-# the PR 5 sweep benchmark): XLA expands every gather into a per-element
-# loop, and the windowed (taps,)^d + (C,) slice expansion is ~3-5x slower
-# per element for C >= 2 than for C = 1.  A per-channel lax.map of the fast
-# C = 1 body restores the good constant (linear in C); for small d and
-# enough channels, a flat-index row take is better still — its ~constant
-# per-index overhead amortizes over the C contiguous channel values.
-_XLA_GATHER_TAKE_MIN_C = 6
-_XLA_GATHER_TAKE_MAX_D = 2
-_XLA_TAKE_TILE_ELEMS = 1 << 18
-
-
-def _xla_gather_take(plan: NfftPlan, geometry: WindowGeometry,
-                     gpad: Array) -> Array:
-    """Flat-index tiled gather: one row take per (node, window element).
-
-    Gathers rows of the channel-flattened grid by precomputed flat indices
-    (static per-plan cube offsets + per-node flat corners) and contracts the
-    weight cube per tile.  Per-index cost is ~constant in C, so this wins
-    for many channels when taps^d is small (d <= 2).
-    """
-    d, taps = plan.d, plan.taps
-    pad_n = padded_grid_size(plan)
-    c = gpad.shape[-1]
-    n = geometry.base.shape[0]
-    gflat = gpad.reshape(-1, c)
-    # static flat offsets of the (taps,)^d window cube (numpy: jit-literal)
-    offs = np.arange(taps)
-    cube = offs
-    for _ in range(d - 1):
-        cube = cube[..., None] * pad_n + offs
-    cube_off = jnp.asarray(cube.reshape(-1), jnp.int32)
-    fb = geometry.base[:, 0]
-    for t in range(1, d):
-        fb = fb * pad_n + geometry.base[:, t]
-    tile = max(64, min(n, _XLA_TAKE_TILE_ELEMS // taps ** d))
-    pad = (-n) % tile
-    fbp = jnp.pad(fb, (0, pad))
-    w = jnp.pad(geometry.weights, ((0, pad), (0, 0), (0, 0)))
-
-    def body(k, acc):
-        fbt = jax.lax.dynamic_slice_in_dim(fbp, k * tile, tile)
-        wt = jax.lax.dynamic_slice_in_dim(w, k * tile, tile, axis=0)
-        idx = (fbt[:, None] + cube_off[None, :]).reshape(-1)
-        vals = jnp.take(gflat, idx, axis=0,
-                        unique_indices=False).reshape(tile, -1, c)
-        wcube = _tile_weight_cube(wt, d).reshape(tile, -1)
-        out = jnp.einsum("ntc,nt->nc", vals, wcube)
-        return jax.lax.dynamic_update_slice_in_dim(acc, out, k * tile, axis=0)
-
-    acc = jnp.zeros((n + pad, c), dtype=gpad.dtype)
-    num_tiles = (n + pad) // tile
-    if num_tiles == 1:
-        return body(0, acc)[:n]
-    return jax.lax.fori_loop(0, num_tiles, body, acc)[:n]
-
-
-def _xla_gather(plan: NfftPlan, geometry: WindowGeometry,
-                gpad: Array) -> Array:
-    """Streaming tiled gather, row order — multi-channel aware.
-
-    Dispatches between three equivalent bodies on the (static) channel
-    count: the whole-window slice gather for C = 1 (XLA's cheap expansion),
-    a flat-index row take for many channels at small d, and a per-channel
-    ``lax.map`` of the C = 1 body otherwise.  The multi-channel paths keep
-    the bank matvec's inverse half from dominating a sweep: the batched
-    windowed gather costs ~3-5x more *per element* as soon as C >= 2.
-    """
-    c = gpad.shape[-1]
-    if c == 1:
-        return _xla_gather_windowed(plan, geometry, gpad)
-    if plan.d <= _XLA_GATHER_TAKE_MAX_D and c >= _XLA_GATHER_TAKE_MIN_C:
-        return _xla_gather_take(plan, geometry, gpad)
-    gm = jnp.moveaxis(gpad, -1, 0)[..., None]  # (C,) + grid + (1,)
-    out = jax.lax.map(
-        lambda g1: _xla_gather_windowed(plan, geometry, g1)[..., 0], gm)
-    return jnp.moveaxis(out, 0, 1)
 
 
 def _channel_chunks(plan: NfftPlan, channels: int) -> list:
@@ -424,19 +344,35 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Differentiable core (custom VJP).
+# The pipeline on flat columns.
 #
-# The pipeline is linear in both x and the spectral multiplier, and
-# window_spread / window_gather are exact mutual adjoints on a shared
-# geometry (same base/weights/perm; verified to 1e-12 by the adjoint test
-# suite).  That gives the whole matvec a closed-form transpose that never
-# differentiates *through* the fori_loop scatter tiles or the Pallas
-# kernels:
+# Every entry point runs one core (:func:`_fused_columns`) on node columns
+# ``xb`` (n, K) against a bank of S multipliers (stacked ``(S,) +
+# half-spectrum`` by :func:`stack_multipliers`, or a sequence of S), in one
+# of two layouts:
+#
+#     broadcast:  the K = C columns are right-hand sides that every member
+#                 is applied to; S*C columns come out, member-major (column
+#                 s*C + j is member s applied to column j);
+#     lockstep:   the K = S*C columns are member-major already, and member s
+#                 is applied to its own C of them (the shape a bank Krylov
+#                 solver iterates on).
+#
+# Either way one spread and one forward rfftn serve all S members.  A single
+# operator is the broadcast bank of one, the sequence of its multiplier:
+# S = 1, C columns in and C out.
+#
+# Differentiable core (custom VJP).  The pipeline is linear in both x and
+# the spectral multipliers, and window_spread / window_gather are exact
+# mutual adjoints on a shared geometry (same base/weights/perm; verified to
+# 1e-12 by the adjoint test suite).  That gives the whole matvec a
+# closed-form transpose that never differentiates *through* the fori_loop
+# scatter tiles or the Pallas kernels:
 #
 #     cotangent wrt x:  spread ybar on the TARGET geometry (gather-adjoint),
 #                       run the adjoint spectral mid-section, gather on the
 #                       SOURCE geometry — one extra pipeline pass;
-#     cotangent wrt multiplier_half:  elementwise product of the forward
+#     cotangent wrt the multipliers:  elementwise product of the forward
 #                       spectrum rfftn(g) and the cotangent spectrum.  The
 #                       rfftn half-spectrum stores each interior Hermitian
 #                       bin once but it appears twice in the full spectrum,
@@ -452,181 +388,9 @@ def window_gather(plan: NfftPlan, geometry: WindowGeometry, g: Array, *,
 #
 # Plan-time geometry (points, Morton windows, permutations) is
 # intentionally NON-differentiable: its cotangents are zero (None).  The
-# distributed/faulted variants (spectral_reduce / spectral_op / grid_hook)
-# bypass the custom VJP and stay forward-only.
-# ---------------------------------------------------------------------------
-
-def _spectral_mid(plan: NfftPlan, multiplier_half: Array, g: Array) -> Array:
-    """rfftn -> multiply -> irfftn on the spread grid (single multiplier)."""
-    d = plan.d
-    with jax.named_scope(scopes.FFT_MID):
-        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-        g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
-        y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
-                           axes=tuple(range(d)))
-        return y.astype(g.dtype)
-
-
-def _bank_multiply(plan: NfftPlan, multiplier_bank: Array, g_hat: Array,
-                   broadcast: bool) -> Array:
-    """Bank spectral multiply -> flat (..., S*C) half-spectrum product."""
-    d = plan.d
-    nb = multiplier_bank.shape[0]
-    mb = jnp.moveaxis(multiplier_bank, 0, -1)  # spectrum + (S,)
-    if broadcast:
-        gh = g_hat[..., None, :]  # spectrum + (1, C): broadcast over S
-    else:
-        c = g_hat.shape[-1] // nb
-        gh = g_hat.reshape(g_hat.shape[:d] + (nb, c))
-    prod = mb[..., :, None].astype(g_hat.dtype) * gh  # spectrum + (S, C)
-    return prod.reshape(prod.shape[:d] + (-1,))
-
-
-def _bank_spectral_mid(plan: NfftPlan, broadcast: bool,
-                       multiplier_bank: Array, g: Array) -> Array:
-    """Bank rfftn -> member-wise multiply -> irfftn (no reduce/op hooks)."""
-    d = plan.d
-    with jax.named_scope(scopes.FFT_MID):
-        g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-        flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
-        y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d,
-                           axes=tuple(range(d)))
-        return y.astype(g.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _diff_pipeline_columns(plan: NfftPlan, backend: str | None,
-                           multiplier_half: Array, src: WindowGeometry,
-                           tgt: WindowGeometry, xb: Array) -> Array:
-    return window_gather(
-        plan, tgt,
-        _spectral_mid(plan, multiplier_half,
-                      window_spread(plan, src, xb, backend=backend)),
-        backend=backend)
-
-
-def _diff_pipeline_columns_fwd(plan, backend, multiplier_half, src, tgt, xb):
-    g = window_spread(plan, src, xb, backend=backend)
-    y, mid_pull = jax.vjp(
-        lambda m, gg: _spectral_mid(plan, m, gg), multiplier_half, g)
-    out = window_gather(plan, tgt, y, backend=backend)
-    return out, (mid_pull, src, tgt)
-
-
-def _diff_pipeline_columns_bwd(plan, backend, res, ybar):
-    mid_pull, src, tgt = res
-    v = window_spread(plan, tgt, ybar, backend=backend)  # gather-adjoint
-    mult_bar, g_bar = mid_pull(v)
-    x_bar = window_gather(plan, src, g_bar, backend=backend)  # spread-adjoint
-    return mult_bar, None, None, x_bar
-
-
-_diff_pipeline_columns.defvjp(_diff_pipeline_columns_fwd,
-                              _diff_pipeline_columns_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _diff_pipeline_bank_columns(plan: NfftPlan, backend: str | None,
-                                broadcast: bool, multiplier_bank: Array,
-                                src: WindowGeometry, tgt: WindowGeometry,
-                                xb: Array) -> Array:
-    return window_gather(
-        plan, tgt,
-        _bank_spectral_mid(plan, broadcast, multiplier_bank,
-                           window_spread(plan, src, xb, backend=backend)),
-        backend=backend)
-
-
-def _diff_pipeline_bank_columns_fwd(plan, backend, broadcast,
-                                    multiplier_bank, src, tgt, xb):
-    g = window_spread(plan, src, xb, backend=backend)
-    y, mid_pull = jax.vjp(
-        lambda m, gg: _bank_spectral_mid(plan, broadcast, m, gg),
-        multiplier_bank, g)
-    out = window_gather(plan, tgt, y, backend=backend)
-    return out, (mid_pull, src, tgt)
-
-
-def _diff_pipeline_bank_columns_bwd(plan, backend, broadcast, res, ybar):
-    mid_pull, src, tgt = res
-    v = window_spread(plan, tgt, ybar, backend=backend)
-    bank_bar, g_bar = mid_pull(v)
-    x_bar = window_gather(plan, src, g_bar, backend=backend)
-    return bank_bar, None, None, x_bar
-
-
-_diff_pipeline_bank_columns.defvjp(_diff_pipeline_bank_columns_fwd,
-                                   _diff_pipeline_bank_columns_bwd)
-
-
-def fused_pipeline(plan: NfftPlan, multiplier_half: Array,
-                   src: WindowGeometry, tgt: WindowGeometry, x: Array,
-                   spectral_reduce=None, backend: str | None = None,
-                   spectral_op=None, grid_hook=None) -> Array:
-    """spread -> rfftn -> multiply -> irfftn -> gather, one traceable body.
-
-    Two hooks let the distributed matvec reuse this single implementation
-    (so the local and distributed pipelines cannot drift apart):
-
-    * ``spectral_reduce`` is applied to the support block of the multiplied
-      half-spectrum (see :func:`spectral_support`) — the psum spectral mode's
-      one cross-shard accumulation.
-    * ``spectral_op``, when given, replaces the whole rfftn -> multiply ->
-      irfftn mid-section: it maps the spread grid ``(M,)*d + (C,)`` (real,
-      FFT order) to the inverse-transformed grid of the same shape.  The
-      pencil spectral mode uses it to run the reduce-scattered, slab-sharded
-      transform of :mod:`repro.dist.pencil_fft`; ``multiplier_half`` and
-      ``spectral_reduce`` are ignored in that case (the op owns the
-      multiply).
-
-    ``backend`` selects the window-step backend (see :func:`resolve_backend`).
-    ``grid_hook``, when given, maps the spread grid ``(M,)*d + (C,)`` to a
-    grid of the same shape before the spectral section — the deterministic
-    fault-injection seam (:mod:`repro.runtime.faultinject` poisons it to
-    model grid memory corruption); production callers leave it ``None``.
-
-    With no hooks this routes through the custom-VJP differentiable core:
-    gradients flow to ``x`` and ``multiplier_half`` via the closed-form
-    transpose pipeline (one extra pass), never through the window scatter
-    loops.  The hooked (distributed / fault-injected) variants stay
-    forward-only.
-    """
-    d = plan.d
-    batched = x.ndim == 2
-    xb = x if batched else x[:, None]
-    if spectral_reduce is None and spectral_op is None and grid_hook is None:
-        out = _diff_pipeline_columns(plan, backend, multiplier_half,
-                                     src, tgt, xb)
-        return out if batched else out[..., 0]
-    g = window_spread(plan, src, xb, backend=backend)
-    if grid_hook is not None:
-        g = grid_hook(g)
-    with jax.named_scope(scopes.FFT_MID):
-        if spectral_op is not None:
-            y = spectral_op(g)
-        else:
-            g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-            g_hat = g_hat * multiplier_half.astype(g_hat.dtype)[..., None]
-            if spectral_reduce is not None:
-                sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
-                block = spectral_reduce(g_hat[tuple(sup)])
-                g_hat = jnp.zeros_like(g_hat).at[tuple(sup)].set(block)
-            y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
-                               axes=tuple(range(d)))
-    out = window_gather(plan, tgt, y.astype(xb.dtype), backend=backend)
-    return out if batched else out[..., 0]
-
-
-@functools.partial(jax.jit, static_argnames=("plan", "backend"))
-def fused_matvec_tilde(plan: NfftPlan, multiplier_half: Array,
-                       src: WindowGeometry, tgt: WindowGeometry,
-                       x: Array, backend: str | None = None) -> Array:
-    """y = W̃ x via the fused pipeline; x: (n,) or (n, C) real."""
-    return fused_pipeline(plan, multiplier_half, src, tgt, x, backend=backend)
-
-
-# ---------------------------------------------------------------------------
-# Multiplier banks: amortize spread + forward FFT across S operators.
+# distributed hooks (spectral_reduce / spectral_op) are differentiated
+# inside the mid-section's jax.vjp, so a hook takes the multipliers as an
+# argument and closes over no traced value.
 # ---------------------------------------------------------------------------
 
 def stack_multipliers(plan: NfftPlan, b_hats) -> Array:
@@ -639,6 +403,134 @@ def stack_multipliers(plan: NfftPlan, b_hats) -> Array:
     return jnp.stack([fused_spectral_multiplier(plan, bh) for bh in b_hats])
 
 
+def bank_multiply(multiplier_bank, g_hat: Array, broadcast: bool) -> Array:
+    """Member-wise spectral multiply -> the (..., S*C) product, member-major.
+
+    ``g_hat`` has its spectrum axes leading and its channels in the layout
+    ``broadcast`` names (see above).  ``multiplier_bank`` holds S
+    multipliers over the same spectrum axes (the whole half-spectrum, or
+    one device's pencil slab of it, :mod:`repro.dist.fastsum_dist`):
+    stacked ``(S,) + spectrum``, or a sequence.  A single operator passes
+    the sequence of its one multiplier, and the product is then exactly
+    ``g_hat * multiplier[..., None]``, with no layout op on either side: it
+    compiles to the program of a lone multiply.
+    """
+    nb = len(multiplier_bank)
+    if not broadcast and g_hat.shape[-1] % nb:
+        raise ValueError(f"lockstep columns must be S*C with S={nb}, "
+                         f"got {g_hat.shape[-1]}")
+    c = g_hat.shape[-1] if broadcast else g_hat.shape[-1] // nb
+    return jnp.concatenate(
+        [(g_hat if broadcast else g_hat[..., s * c:(s + 1) * c])
+         * multiplier_bank[s].astype(g_hat.dtype)[..., None]
+         for s in range(nb)], axis=-1)
+
+
+def _spectral_mid(plan: NfftPlan, multiplier_bank, broadcast: bool,
+                  g: Array, spectral_reduce=None, spectral_op=None) -> Array:
+    """The mid-section on the spread grid ``(M,)*d + (K,)`` -> the
+    inverse-transformed grid with the output's columns (FFT order).
+
+    rfftn -> :func:`bank_multiply` -> irfftn, with ``spectral_reduce``
+    applied to the support block of the multiplied half-spectrum
+    (:func:`spectral_support`) when given; or
+    ``spectral_op(g, multiplier_bank)`` in place of the whole section.
+    """
+    d = plan.d
+    with jax.named_scope(scopes.FFT_MID):
+        if spectral_op is not None:
+            y = spectral_op(g, multiplier_bank)
+        else:
+            g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
+            g_hat = bank_multiply(multiplier_bank, g_hat, broadcast)
+            if spectral_reduce is not None:
+                sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
+                block = spectral_reduce(g_hat[tuple(sup)])
+                g_hat = jnp.zeros_like(g_hat).at[tuple(sup)].set(block)
+            y = jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * d,
+                               axes=tuple(range(d)))
+        return y.astype(g.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _fused_columns(plan: NfftPlan, backend: str | None, broadcast: bool,
+                   spectral_reduce, spectral_op, multiplier_bank,
+                   src: WindowGeometry, tgt: WindowGeometry,
+                   xb: Array) -> Array:
+    """spread -> rfftn -> multiply -> irfftn -> gather on flat columns.
+
+    ``xb`` (n, K) in the layout ``broadcast`` names (see above); returns
+    (n_tgt, S*C).  Two hooks let the distributed matvec reuse this single
+    implementation (so the local and distributed pipelines cannot drift
+    apart):
+
+    * ``spectral_reduce`` is applied to the support block of the multiplied
+      half-spectrum (see :func:`spectral_support`) — the psum spectral
+      mode's one cross-shard accumulation.
+    * ``spectral_op``, when given, replaces the whole rfftn -> multiply ->
+      irfftn mid-section: it maps the spread grid ``(M,)*d + (K,)`` (real,
+      FFT order) and ``multiplier_bank`` to the inverse-transformed grid
+      with ``S*C`` channels.  The pencil spectral mode uses it to run the
+      reduce-scattered, slab-sharded transform of
+      :mod:`repro.dist.pencil_fft`; the op owns the multiply, and
+      ``spectral_reduce`` is ignored.
+
+    Gradients flow to ``xb`` and ``multiplier_bank`` via the closed-form
+    transpose pipeline (one extra pass), never through the window scatter
+    loops.
+    """
+    g = window_spread(plan, src, xb, backend=backend)
+    y = _spectral_mid(plan, multiplier_bank, broadcast, g,
+                      spectral_reduce=spectral_reduce,
+                      spectral_op=spectral_op)
+    return window_gather(plan, tgt, y, backend=backend)
+
+
+def _fused_columns_fwd(plan, backend, broadcast, spectral_reduce,
+                       spectral_op, multiplier_bank, src, tgt, xb):
+    g = window_spread(plan, src, xb, backend=backend)
+    y, mid_pull = jax.vjp(
+        lambda m, gg: _spectral_mid(plan, m, broadcast, gg,
+                                    spectral_reduce=spectral_reduce,
+                                    spectral_op=spectral_op),
+        multiplier_bank, g)
+    out = window_gather(plan, tgt, y, backend=backend)
+    return out, (mid_pull, src, tgt)
+
+
+def _fused_columns_bwd(plan, backend, broadcast, spectral_reduce,
+                       spectral_op, res, ybar):
+    mid_pull, src, tgt = res
+    v = window_spread(plan, tgt, ybar, backend=backend)  # gather-adjoint
+    bank_bar, g_bar = mid_pull(v)
+    x_bar = window_gather(plan, src, g_bar, backend=backend)  # spread-adjoint
+    return bank_bar, None, None, x_bar
+
+
+_fused_columns.defvjp(_fused_columns_fwd, _fused_columns_bwd)
+
+
+def fused_pipeline(plan: NfftPlan, multiplier_half: Array,
+                   src: WindowGeometry, tgt: WindowGeometry, x: Array,
+                   spectral_reduce=None, backend: str | None = None,
+                   spectral_op=None) -> Array:
+    """One operator, ``x`` (n,) or (n, C): the flat-column core as the
+    broadcast bank of one (hooks and gradients as :func:`_fused_columns`;
+    ``backend`` selects the window step, see :func:`resolve_backend`)."""
+    xb = x if x.ndim == 2 else x[:, None]
+    out = _fused_columns(plan, backend, True, spectral_reduce, spectral_op,
+                         (multiplier_half,), src, tgt, xb)
+    return out if x.ndim == 2 else out[..., 0]
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "backend"))
+def fused_matvec_tilde(plan: NfftPlan, multiplier_half: Array,
+                       src: WindowGeometry, tgt: WindowGeometry,
+                       x: Array, backend: str | None = None) -> Array:
+    """y = W̃ x via the fused pipeline; x: (n,) or (n, C) real."""
+    return fused_pipeline(plan, multiplier_half, src, tgt, x, backend=backend)
+
+
 def fused_pipeline_bank(plan: NfftPlan, multiplier_bank: Array,
                         src: WindowGeometry, tgt: WindowGeometry, x: Array,
                         spectral_reduce=None, backend: str | None = None,
@@ -649,24 +541,16 @@ def fused_pipeline_bank(plan: NfftPlan, multiplier_bank: Array,
     :func:`stack_multipliers`).  Two input flavors, distinguished by rank:
 
     * **broadcast** — ``x`` of shape (n,) or (n, C): every member is applied
-      to the same right-hand sides.  The spread and forward rfftn run once
-      with C channels; the S cheap diagonal multiplies, one *batched* irfftn
-      over S*C channels, and one gather with S*C channels produce
-      ``(S, n)`` / ``(S, n, C)``.  An S-point multiplier sweep costs ~one
-      matvec plus S spectral multiplies instead of S full pipelines.
+      to the same right-hand sides, giving ``(S, n)`` / ``(S, n, C)``.  An
+      S-point multiplier sweep costs ~one matvec plus S spectral multiplies
+      instead of S full pipelines.
 
     * **lockstep** — ``x`` of shape (S, n, C): member ``s`` is applied to
-      ``x[s]`` (the shape a bank Krylov solver iterates on).  The S*C system
-      columns ride the channel axis end to end — still exactly one spread,
-      one forward rfftn, one irfftn, one gather.
+      ``x[s]``; the S*C system columns ride the channel axis end to end.
 
-    ``spectral_reduce`` / ``spectral_op`` mirror :func:`fused_pipeline`:
-    the reduce hits the support block of the multiplied half-spectrum with
-    the bank stacked into the channel axis (the distributed psum mode's one
-    collective); ``spectral_op``, when given, replaces the whole rfftn ->
-    multiply -> irfftn mid-section and must map the spread grid to an
-    inverse-transformed grid with ``S*C`` trailing channels (it owns the
-    bank multiply — the pencil mode's per-device multiplier slabs).
+    Both are reshapes around :func:`_fused_columns` (hooks as there, with
+    the bank stacked into the channel axis: the distributed psum mode's one
+    collective carries all S members).
     """
     nb = multiplier_bank.shape[0]
     lockstep = x.ndim == 3
@@ -674,78 +558,35 @@ def fused_pipeline_bank(plan: NfftPlan, multiplier_bank: Array,
         if x.shape[0] != nb:
             raise ValueError(
                 f"lockstep x has bank axis {x.shape[0]}, bank has {nb}")
-        c = x.shape[-1]
-        xb = jnp.moveaxis(x, 0, 1).reshape(x.shape[1], nb * c)
+        xb = jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1)
     else:
-        batched = x.ndim == 2
-        xb = x if batched else x[:, None]
-        c = xb.shape[-1]
-    out = _bank_columns_core(plan, multiplier_bank, src, tgt, xb,
-                             broadcast=not lockstep,
-                             spectral_reduce=spectral_reduce,
-                             backend=backend, spectral_op=spectral_op)
-    out = jnp.moveaxis(out.reshape(out.shape[0], nb, c), 0, 1)  # (S, n, C)
-    if lockstep:
-        return out
-    return out if batched else out[..., 0]
+        xb = x if x.ndim == 2 else x[:, None]
+    out = _fused_columns(plan, backend, not lockstep, spectral_reduce,
+                         spectral_op, multiplier_bank, src, tgt, xb)
+    out = jnp.moveaxis(out.reshape(out.shape[0], nb, -1), 0, 1)  # (S, n, C)
+    return out if x.ndim >= 2 else out[..., 0]
 
 
-def _bank_columns_transform(plan: NfftPlan, multiplier_bank: Array,
-                            src: WindowGeometry, xb: Array,
-                            *, broadcast: bool, spectral_reduce=None,
-                            backend: str | None = None,
-                            spectral_op=None) -> Array:
-    """Gather-free half of the bank pipeline: spread -> rfftn -> multiply ->
-    irfftn, returning the inverse-transformed grid (FFT order).
-
-    ``xb`` is (n, K): the spread/FFT channel lanes.  ``broadcast=True``
-    treats all K columns as shared right-hand sides and expands them
-    against every member (output K*S channels, S-major); ``broadcast=False``
-    treats K = S*C bank-major lockstep columns (column ``s*C + j`` belongs
-    to member ``s``) and multiplies member-wise (output K channels).
-
-    The grid this returns depends only on the source side (nodes, spectral
-    multipliers, right-hand sides) — any number of target sets can be
-    gathered from it afterwards (:func:`window_gather` /
-    :func:`fused_gather_columns`), which is what the serving tier caches
-    per (model, dual-vector) column.
-    """
-    d = plan.d
-    g = window_spread(plan, src, xb, backend=backend)
-    with jax.named_scope(scopes.FFT_MID):
-        if spectral_op is not None:
-            # (M,)*d + (S*C,): the op owns the bank multiply
-            y = spectral_op(g)
-        else:
-            g_hat = jnp.fft.rfftn(g, axes=tuple(range(d)))
-            flat = _bank_multiply(plan, multiplier_bank, g_hat, broadcast)
-            if spectral_reduce is not None:
-                sup = jnp.meshgrid(*spectral_support(plan), indexing="ij")
-                block = spectral_reduce(flat[tuple(sup)])
-                flat = jnp.zeros_like(flat).at[tuple(sup)].set(block)
-            y = jnp.fft.irfftn(flat, s=(plan.grid_size,) * d,
-                               axes=tuple(range(d)))
-        return y.astype(xb.dtype)
+@functools.partial(jax.jit, static_argnames=("plan", "backend"))
+def fused_matvec_tilde_bank(plan: NfftPlan, multiplier_bank: Array,
+                            src: WindowGeometry, tgt: WindowGeometry,
+                            x: Array, backend: str | None = None) -> Array:
+    """y[s] = W̃_s x (broadcast) or W̃_s x[s] (lockstep); see
+    :func:`fused_pipeline_bank`."""
+    return fused_pipeline_bank(plan, multiplier_bank, src, tgt, x,
+                               backend=backend)
 
 
-def _bank_columns_core(plan: NfftPlan, multiplier_bank: Array,
-                       src: WindowGeometry, tgt: WindowGeometry, xb: Array,
-                       *, broadcast: bool, spectral_reduce=None,
-                       backend: str | None = None, spectral_op=None) -> Array:
-    """Full bank pipeline body in flat column layout (transform + gather).
-
-    Hook-free calls route through the custom-VJP differentiable bank core
-    (gradients to ``multiplier_bank`` and ``xb`` via the transpose
-    pipeline); the distributed variants stay forward-only.
-    """
-    if spectral_reduce is None and spectral_op is None:
-        return _diff_pipeline_bank_columns(plan, backend, broadcast,
-                                           multiplier_bank, src, tgt, xb)
-    y = _bank_columns_transform(plan, multiplier_bank, src, xb,
-                                broadcast=broadcast,
-                                spectral_reduce=spectral_reduce,
-                                backend=backend, spectral_op=spectral_op)
-    return window_gather(plan, tgt, y, backend=backend)
+@functools.partial(jax.jit, static_argnames=("plan", "backend"))
+def fused_matvec_tilde_bank_columns(plan: NfftPlan, multiplier_bank: Array,
+                                    src: WindowGeometry,
+                                    tgt: WindowGeometry, u: Array,
+                                    backend: str | None = None) -> Array:
+    """Lockstep bank matvec on flat columns: (n, S*C) -> (n, S*C), column
+    ``s*C + j`` member ``s``'s — the layout the lockstep solvers iterate
+    on, so a bank Krylov iteration moves no bank axis."""
+    return _fused_columns(plan, backend, False, None, None, multiplier_bank,
+                          src, tgt, u)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "backend"))
@@ -756,14 +597,15 @@ def fused_transform_columns(plan: NfftPlan, multiplier_columns: Array,
     multiplier ``j`` of ``multiplier_columns`` ((K,) + half-spectrum) ->
     grid ``(M,)*d + (K,)`` (real, FFT order).
 
-    One spread + one forward rfftn + one batched irfftn for all K columns;
-    the result is the gather-ready state of the prediction pipeline, so a
-    serving tick that caches it per (model, dual-vector) column pays only
-    a target-geometry build and one packed gather per tick
-    (:func:`fused_gather_columns`).
+    The core's gather-free half in lockstep layout (S = K, C = 1): one
+    spread + one forward rfftn + one batched irfftn for all K columns.  The
+    grid depends only on the source side, so any number of target sets can
+    be gathered from it afterwards: a serving tick that caches it per
+    (model, dual-vector) column pays only a target-geometry build and one
+    packed gather per tick (:func:`fused_gather_columns`).
     """
-    return _bank_columns_transform(plan, multiplier_columns, src, xb,
-                                   broadcast=False, backend=backend)
+    g = window_spread(plan, src, xb, backend=backend)
+    return _spectral_mid(plan, multiplier_columns, False, g)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "backend"))
@@ -782,46 +624,3 @@ def fused_gather_columns(plan: NfftPlan, tgt: WindowGeometry, grid: Array,
     out = window_gather(plan, tgt, grid, backend=backend)  # (m, K)
     idx = col_index.astype(jnp.int32)[:, None]
     return jnp.take_along_axis(out, idx, axis=1)[:, 0]
-
-
-def fused_pipeline_bank_columns(plan: NfftPlan, multiplier_bank: Array,
-                                src: WindowGeometry, tgt: WindowGeometry,
-                                u: Array, spectral_reduce=None,
-                                backend: str | None = None,
-                                spectral_op=None) -> Array:
-    """Lockstep bank matvec in flat column-major layout: (n, S*C) -> same.
-
-    Column ``s*C + j`` belongs to member ``s`` — exactly the layout the
-    lockstep solvers iterate on, so a bank Krylov iteration runs with ZERO
-    bank-axis transposes (the (S, n, C) flavor of
-    :func:`fused_pipeline_bank` costs four (n, S*C)-sized copies per call
-    just moving the bank axis in and out).
-    """
-    nb = multiplier_bank.shape[0]
-    if u.ndim != 2 or u.shape[-1] % nb:
-        raise ValueError(
-            f"columns input must be (n, S*C) with S={nb}, got {u.shape}")
-    return _bank_columns_core(plan, multiplier_bank, src, tgt, u,
-                              broadcast=False,
-                              spectral_reduce=spectral_reduce,
-                              backend=backend, spectral_op=spectral_op)
-
-
-@functools.partial(jax.jit, static_argnames=("plan", "backend"))
-def fused_matvec_tilde_bank(plan: NfftPlan, multiplier_bank: Array,
-                            src: WindowGeometry, tgt: WindowGeometry,
-                            x: Array, backend: str | None = None) -> Array:
-    """y[s] = W̃_s x (broadcast) or W̃_s x[s] (lockstep); see
-    :func:`fused_pipeline_bank`."""
-    return fused_pipeline_bank(plan, multiplier_bank, src, tgt, x,
-                               backend=backend)
-
-
-@functools.partial(jax.jit, static_argnames=("plan", "backend"))
-def fused_matvec_tilde_bank_columns(plan: NfftPlan, multiplier_bank: Array,
-                                    src: WindowGeometry,
-                                    tgt: WindowGeometry, u: Array,
-                                    backend: str | None = None) -> Array:
-    """Jitted :func:`fused_pipeline_bank_columns` (the solver hot loop)."""
-    return fused_pipeline_bank_columns(plan, multiplier_bank, src, tgt, u,
-                                       backend=backend)

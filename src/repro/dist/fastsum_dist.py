@@ -30,8 +30,8 @@ accumulation (``distributed_matvec_fn(..., spectral_mode=...)``):
     the grid (a degenerate pencil would psum the full grid — strictly
     worse).
 
-``_spectral_matvec_local`` keeps the seed two-NFFT body (full ``N^d``
-psum); it survives only as an oracle.
+A single operator runs as the bank of one (:mod:`repro.core.fastsum_exec`),
+so each spectral mode has one per-shard body, bank-shaped.
 """
 
 from __future__ import annotations
@@ -45,8 +45,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import fastsum_exec, nfft as nfft_mod
-from repro.core.nfft import NfftGeometry, NfftPlan, WindowGeometry
+from repro.core import fastsum_exec
+from repro.core.nfft import NfftPlan, WindowGeometry
 from repro.dist import pencil_fft
 
 Array = jax.Array
@@ -54,71 +54,15 @@ Array = jax.Array
 SPECTRAL_MODES = ("psum", "pencil")
 
 
-def _spectral_matvec_local(plan: NfftPlan, b_hat: Array,
-                           geometry: NfftGeometry, x: Array,
-                           axes: tuple[str, ...],
-                           tgt_geometry: NfftGeometry | None = None) -> Array:
-    """Per-shard body of the seed two-NFFT distributed matvec (oracle only).
-
-    ``geometry``/``x`` hold this shard's slice of the node dimension;
-    ``b_hat`` is replicated.  The one cross-shard collective is the psum of
-    the adjoint's full ``N^d`` spectral coefficients.
-    """
-    tgt = geometry if tgt_geometry is None else tgt_geometry
-    x_hat = nfft_mod.nfft_adjoint(plan, geometry, x)
-    if axes:
-        x_hat = jax.lax.psum(x_hat, axes)
-    f_hat = b_hat[..., None] * x_hat if x.ndim == 2 else b_hat * x_hat
-    f = nfft_mod.nfft_forward(plan, tgt, f_hat)
-    return jnp.real(f).astype(x.dtype)
-
-
-def _fused_matvec_local(plan: NfftPlan, mult_half: Array,
-                        geometry: WindowGeometry, x: Array,
-                        axes: tuple[str, ...],
-                        backend: str | None = None) -> Array:
-    """Per-shard psum-mode body of the fused distributed matvec.
-
-    The one cross-shard collective is the psum of the multiplied
-    half-spectrum restricted to the multiplier's support block (~N^d/2
-    complex: the entire wire payload), injected into the shared
-    single-device pipeline via its ``spectral_reduce`` hook.
-    """
-    reduce = (lambda block: jax.lax.psum(block, axes)) if axes else None
-    return fastsum_exec.fused_pipeline(plan, mult_half, geometry, geometry,
-                                       x, spectral_reduce=reduce,
-                                       backend=backend)
-
-
-def _pencil_matvec_local(plan: NfftPlan, mult_half: Array,
-                         geometry: WindowGeometry, x: Array,
-                         spec: pencil_fft.PencilSpec,
-                         backend: str | None = None) -> Array:
-    """Per-shard pencil-mode body: the ``spectral_op`` hook replaces the
-    whole rfftn -> multiply -> irfftn mid-section with the reduce-scattered,
-    slab-sharded transform."""
-
-    def spectral_op(g):
-        pencil = pencil_fft.pencil_accumulate(g, spec)
-        gh = pencil_fft.pencil_rfftn(pencil, spec)
-        slab = pencil_fft.multiplier_slab(mult_half, spec)
-        gh = gh * slab.astype(gh.dtype)[..., None]
-        y = pencil_fft.pencil_irfftn(gh, spec)
-        return pencil_fft.pencil_allgather(y, spec).astype(g.dtype)
-
-    return fastsum_exec.fused_pipeline(plan, mult_half, geometry, geometry,
-                                       x, backend=backend,
-                                       spectral_op=spectral_op)
-
-
-def _fused_matvec_bank_local(plan: NfftPlan, mult_bank: Array,
-                             geometry: WindowGeometry, x: Array,
-                             axes: tuple[str, ...],
-                             backend: str | None = None) -> Array:
-    """Per-shard psum-mode bank body: ONE psum of the *stacked* multiplier
-    support blocks (the S·C system columns ride the channel axis, so the
-    wire payload is the single-operator support block times S·C — still one
-    collective, and still one spread + one forward FFT per shard)."""
+def _psum_body(plan: NfftPlan, mult_bank: Array, geometry: WindowGeometry,
+               x: Array, axes: tuple[str, ...],
+               backend: str | None = None) -> Array:
+    """Per-shard psum-mode body: ONE psum of the multiplied half-spectrum
+    restricted to the multipliers' support block (~N^d/2 complex per system
+    column: the entire wire payload), injected into the shared pipeline via
+    its ``spectral_reduce`` hook.  The S·C system columns ride the channel
+    axis, so it stays one collective after one spread and one forward FFT
+    per shard."""
     reduce = (lambda block: jax.lax.psum(block, axes)) if axes else None
     return fastsum_exec.fused_pipeline_bank(plan, mult_bank, geometry,
                                             geometry, x,
@@ -126,32 +70,24 @@ def _fused_matvec_bank_local(plan: NfftPlan, mult_bank: Array,
                                             backend=backend)
 
 
-def _pencil_matvec_bank_local(plan: NfftPlan, mult_bank: Array,
-                              geometry: WindowGeometry, x: Array,
-                              spec: pencil_fft.PencilSpec,
-                              backend: str | None = None) -> Array:
-    """Per-shard pencil-mode bank body: per-device ``(S, slab)`` multiplier
-    slabs (the vmapped :func:`pencil_fft.multiplier_slab`) multiply the
-    shared pencil spectrum member-wise; one reduce_scatter / all_gather pair
-    moves the S·C-channel pencils."""
-    nb = mult_bank.shape[0]
-    c = x.shape[-1] if x.ndim >= 2 else 1
-    lockstep = x.ndim == 3
+def _pencil_body(plan: NfftPlan, mult_bank: Array, geometry: WindowGeometry,
+                 x: Array, spec: pencil_fft.PencilSpec,
+                 backend: str | None = None) -> Array:
+    """Per-shard pencil-mode body: the ``spectral_op`` hook replaces the
+    whole rfftn -> multiply -> irfftn mid-section with the reduce-scattered,
+    slab-sharded transform.  Per-device ``(S, slab)`` multiplier slabs (the
+    vmapped :func:`pencil_fft.multiplier_slab`) multiply the shared pencil
+    spectrum member-wise; one reduce_scatter / all_gather pair moves the
+    S·C-channel pencils."""
 
-    def spectral_op(g):
+    def spectral_op(g, bank):
         pencil = pencil_fft.pencil_accumulate(g, spec)
         gh = pencil_fft.pencil_rfftn(pencil, spec)
         slabs = jax.vmap(
-            lambda m: pencil_fft.multiplier_slab(m, spec))(mult_bank)
-        slabs = jnp.moveaxis(slabs, 0, -1)  # slab spectrum + (S,)
-        if lockstep:
-            ghb = gh.reshape(gh.shape[:-1] + (nb, c))
-        else:
-            ghb = gh[..., None, :]  # broadcast the shared spectrum over S
-        prod = slabs[..., :, None].astype(gh.dtype) * ghb
-        flat = prod.reshape(prod.shape[:-2] + (nb * c,))
-        y = pencil_fft.pencil_irfftn(flat, spec)
-        return pencil_fft.pencil_allgather(y, spec).astype(g.dtype)
+            lambda m: pencil_fft.multiplier_slab(m, spec))(bank)
+        gh = fastsum_exec.bank_multiply(slabs, gh, broadcast=x.ndim < 3)
+        y = pencil_fft.pencil_irfftn(gh, spec)
+        return pencil_fft.pencil_allgather(y, spec)
 
     return fastsum_exec.fused_pipeline_bank(plan, mult_bank, geometry,
                                             geometry, x, backend=backend,
@@ -193,44 +129,23 @@ def _note_pencil_fallback(plan: NfftPlan, mesh) -> None:
         RuntimeWarning, stacklevel=3)
 
 
-def make_sharded_matvec(plan: NfftPlan, mesh, axes, *,
-                        spectral_mode: str = "psum",
-                        backend: str | None = None, pencil_axes=None,
-                        jit: bool = True):
+def make_sharded_matvec(plan: NfftPlan, mesh, axes, *, jit: bool = True,
+                        **kw):
     """shard_map'd matvec body ``(mult_half, base, w1d, x) -> y`` (row order).
 
     Operands 1..3 are sharded along the node dimension over ``axes``; the
-    multiplier is replicated.  Shared by :func:`distributed_matvec_fn` and
-    the dry-run graph cells, so what the 512-chip cells lower is literally
-    the shipped matvec.  ``jit=False`` returns the bare shard_map'd function
+    multiplier is replicated.  :func:`make_sharded_matvec_bank` at S = 1,
+    broadcast (``kw``: its ``spectral_mode``, ``backend``,
+    ``pencil_axes``).  Shared by :func:`distributed_matvec_fn`'s callers
+    and the dry-run graph cells, so what the 512-chip cells lower is
+    literally the shipped matvec.  ``jit=False`` returns the bare function
     (the dry-run jits it with explicit in_shardings).
     """
-    axes = tuple(axes)
-    if spectral_mode not in SPECTRAL_MODES:
-        raise ValueError(
-            f"spectral_mode must be one of {SPECTRAL_MODES}, "
-            f"got {spectral_mode!r}")
-    spec = None
-    if spectral_mode == "pencil":
-        spec = resolve_pencil_spec(plan, mesh, axes, pencil_axes)
-        if spec is None:
-            _note_pencil_fallback(plan, mesh)
+    bank_mv = make_sharded_matvec_bank(plan, mesh, axes, lockstep=False,
+                                       jit=False, **kw)
 
-    @functools.partial(shard_map, mesh=mesh,
-                       in_specs=(P(), P(axes, None), P(axes, None, None),
-                                 P(axes, None)),
-                       out_specs=P(axes, None), check_vma=False)
     def _mv(mult_half, base_, w_, x_):
-        # rows are globally Morton-sorted and the caller pre-permutes x, so
-        # the per-shard rows already are in order: no perm (no local take
-        # and no inverse-permutation scatter, which the TPU compiler cannot
-        # build inside shard_map)
-        local = WindowGeometry(base=base_, weights=w_, perm=None)
-        if spec is not None:
-            return _pencil_matvec_local(plan, mult_half, local, x_, spec,
-                                        backend=backend)
-        return _fused_matvec_local(plan, mult_half, local, x_, axes,
-                                   backend=backend)
+        return bank_mv(mult_half[None], base_, w_, x_)[0]
 
     return jax.jit(_mv) if jit else _mv
 
@@ -242,17 +157,18 @@ def make_sharded_matvec_bank(plan: NfftPlan, mesh, axes, *,
                              jit: bool = True):
     """shard_map'd bank matvec body ``(mult_bank, base, w1d, x) -> y``.
 
-    The bank analogue of :func:`make_sharded_matvec`: the multiplier *bank*
-    ``(S,) + half-spectrum`` is replicated, the window geometry and the node
-    dimension of ``x`` are sharded over ``axes``, and the output is
-    ``(S, rows, C)`` with only the row axis sharded.  ``lockstep`` is the
-    static input flavor: False takes ``x`` (rows, C) (every member applied
-    to the same columns — spread runs with C channels), True takes ``x``
-    (S, rows, C) (member s applied to x[s], the bank Krylov shape — the S·C
-    system columns ride the channel axis).  Either way each shard runs ONE
-    spread and ONE forward transform, and the cross-shard accumulation is a
-    single collective: the psum of the stacked support blocks, or the
-    pencil reduce_scatter with per-device ``(S, slab)`` multiplier slabs.
+    The multiplier *bank* ``(S,) + half-spectrum`` is replicated, the window
+    geometry and the node dimension of ``x`` are sharded over ``axes``, and
+    the output is ``(S, rows, C)`` with only the row axis sharded.
+    ``lockstep`` is the static input flavor: False takes ``x`` (rows, C)
+    (every member applied to the same columns — spread runs with C
+    channels), True takes ``x`` (S, rows, C) (member s applied to x[s], the
+    bank Krylov shape — the S·C system columns ride the channel axis).
+    Either way each shard runs ONE spread and ONE forward transform, and the
+    cross-shard accumulation is a single collective: the psum of the stacked
+    support blocks, or the pencil reduce_scatter with per-device
+    ``(S, slab)`` multiplier slabs.  ``jit=False`` returns the bare
+    shard_map'd function.
     """
     axes = tuple(axes)
     if spectral_mode not in SPECTRAL_MODES:
@@ -271,26 +187,36 @@ def make_sharded_matvec_bank(plan: NfftPlan, mesh, axes, *,
                                  x_spec),
                        out_specs=P(None, axes, None), check_vma=False)
     def _mv(mult_bank, base_, w_, x_):
+        # rows are globally Morton-sorted and the caller pre-permutes x, so
+        # the per-shard rows already are in order: no perm (no local take
+        # and no inverse-permutation scatter, which the TPU compiler cannot
+        # build inside shard_map)
         local = WindowGeometry(base=base_, weights=w_, perm=None)
         if spec is not None:
-            return _pencil_matvec_bank_local(plan, mult_bank, local, x_,
-                                             spec, backend=backend)
-        return _fused_matvec_bank_local(plan, mult_bank, local, x_, axes,
-                                        backend=backend)
+            return _pencil_body(plan, mult_bank, local, x_, spec,
+                                backend=backend)
+        return _psum_body(plan, mult_bank, local, x_, axes, backend=backend)
 
     return jax.jit(_mv) if jit else _mv
 
 
-def _pad_ghost_geometry(win: WindowGeometry, n: int, nshard: int):
-    """Ghost-pad a window geometry so the node dimension shards evenly.
+def _sharded_bank_apply(plan: NfftPlan, win: WindowGeometry, n: int, mesh,
+                        axes, **kw):
+    """``apply(mult_bank, x)``: the bank kernel sums ``W̃_s`` in node order,
+    the node dimension sharded over ``axes`` of ``mesh`` (``kw``: the
+    spectral mode, backend and pencil axes of
+    :func:`make_sharded_matvec_bank`).
 
-    Ghost rows carry zero window weights (no spread/gather contribution)
-    and identity perm entries.  Returns ``(base, w1d, perm, inv_perm,
-    pad)``; ``inv_perm`` (a concrete numpy argsort) lets callers unsort
-    results with a row *take* — the equivalent multi-channel row scatter
-    costs ~10x more on XLA CPU.
+    ``x`` (n,) / (n, C) is broadcast, giving (S, n) / (S, n, C); ``x``
+    (S, n, C) is lockstep.  The node count is padded with ghost nodes to a
+    multiple of the shard count, so any (n, mesh) combination works: ghost
+    rows carry zero window weights (no spread/gather contribution) and
+    identity perm entries.  The rows go into the geometry's Morton order
+    before the sharded body and come back by a row *take* of the inverse
+    permutation (a concrete numpy argsort; the equivalent multi-channel row
+    scatter costs ~10x more on XLA CPU).
     """
-    pad = (-n) % nshard
+    pad = (-n) % int(np.prod([mesh.shape[a] for a in axes]))
     base, w1d, perm = win.base, win.weights, win.perm
     if pad:
         base = jnp.pad(base, ((0, pad), (0, 0)))
@@ -298,19 +224,27 @@ def _pad_ghost_geometry(win: WindowGeometry, n: int, nshard: int):
         perm = jnp.concatenate(
             [perm, jnp.arange(n, n + pad, dtype=perm.dtype)])
     inv_perm = jnp.asarray(np.argsort(np.asarray(perm)), perm.dtype)
-    return base, w1d, perm, inv_perm, pad
+    # the take reads rows from every shard, so its output sharding cannot be
+    # inferred from a node-sharded operand; naming it (replicated, like the
+    # caller's x) lets the same code run on meshes with Auto and with
+    # Explicit axes (jax.make_mesh's default)
+    replicated = NamedSharding(mesh, P())
+    # both flavors are lazy (jax.jit traces on first call), so building the
+    # unused one costs nothing
+    bodies = {lockstep: make_sharded_matvec_bank(plan, mesh, axes,
+                                                 lockstep=lockstep, **kw)
+              for lockstep in (False, True)}
 
+    def apply(mult_bank: Array, x: Array) -> Array:
+        xb = x if x.ndim >= 2 else x[:, None]
+        if pad:
+            xb = jnp.pad(xb, ((0, 0),) * (xb.ndim - 2) + ((0, pad), (0, 0)))
+        y_sorted = bodies[x.ndim == 3](mult_bank, base, w1d,
+                                       xb[..., perm, :])
+        y = y_sorted.at[:, inv_perm].get(out_sharding=replicated)[:, :n]
+        return y if x.ndim >= 2 else y[..., 0]
 
-def _unsort_rows(y_sorted: Array, inv_perm: Array, mesh, axis: int) -> Array:
-    """Row take back into node order, replicated over ``mesh``.
-
-    The take reads rows from every shard, so its output sharding cannot be
-    inferred from a node-sharded operand; naming it (replicated, like the
-    caller's ``x``) lets the same code run on meshes with Auto and with
-    Explicit axes (``jax.make_mesh``'s default).
-    """
-    idx = (slice(None),) * axis + (inv_perm,)
-    return y_sorted.at[idx].get(out_sharding=NamedSharding(mesh, P()))
+    return apply
 
 
 def distributed_matvec_fn(op, mesh, axes, *, backend: str | None = None,
@@ -327,39 +261,20 @@ def distributed_matvec_fn(op, mesh, axes, *, backend: str | None = None,
     spectral accumulation (see module docstring); ``pencil_axes`` optionally
     overrides the pencil row/col mesh-axis split.
     """
-    plan = op.plan
-    axes = tuple(axes)
     # op.matvec's own contract: the K(0)-diagonal subtraction is only valid
     # when source and target nodes coincide.  A same-length but distinct
     # target set (e.g. the KRR prediction operator) must fail loudly here,
     # not silently evaluate the forward NFFT at the wrong nodes.
     assert op.scaled_tgt is None, \
         "distributed matvec requires src == tgt nodes (shared geometry)"
-    assert op.multiplier_half is not None and op.src_window is not None, \
-        "distributed matvec requires a fused operator (build via make_fastsum)"
-    n = op.n_source
-    nshard = int(np.prod([mesh.shape[a] for a in axes]))
-    base, w1d, perm, inv_perm, pad = _pad_ghost_geometry(
-        op.src_window, n, nshard)
-
-    _mv = make_sharded_matvec(plan, mesh, axes, spectral_mode=spectral_mode,
-                              backend=backend, pencil_axes=pencil_axes)
-
+    apply = _sharded_bank_apply(op.plan, op.src_window, op.n_source, mesh,
+                                tuple(axes), spectral_mode=spectral_mode,
+                                backend=backend, pencil_axes=pencil_axes)
     out_scale = op.output_scale
     k0 = op.kernel_at_zero
 
     def matvec(x: Array) -> Array:
-        batched = x.ndim == 2
-        xp = x if batched else x[:, None]
-        if pad:
-            xp = jnp.pad(xp, ((0, pad), (0, 0)))
-        y_sorted = _mv(op.multiplier_half, base, w1d, xp[perm])
-        y = _unsort_rows(y_sorted, inv_perm, mesh, 0)
-        if pad:
-            y = y[:n]
-        if not batched:
-            y = y[..., 0]
-        return y * out_scale - k0 * x
+        return apply(op.multiplier_half[None], x)[0] * out_scale - k0 * x
 
     return matvec
 
@@ -378,42 +293,16 @@ def distributed_matvec_bank_fn(bank, mesh, axes, *,
     :func:`distributed_matvec_fn`; the one cross-shard collective carries
     the bank stacked into the channel axis.
     """
-    plan = bank.plan
-    axes = tuple(axes)
     assert bank.scaled_tgt is None, \
         "distributed bank matvec requires src == tgt nodes (shared geometry)"
-    n = bank.n_source
-    nshard = int(np.prod([mesh.shape[a] for a in axes]))
-    base, w1d, perm, inv_perm, pad = _pad_ghost_geometry(
-        bank.src_window, n, nshard)
-
-    kw = dict(spectral_mode=spectral_mode, backend=backend,
-              pencil_axes=pencil_axes)
-    # both flavors are lazy (jax.jit traces on first call), so building the
-    # unused one costs nothing
-    _mv_bcast = make_sharded_matvec_bank(plan, mesh, axes, lockstep=False,
-                                         **kw)
-    _mv_lock = make_sharded_matvec_bank(plan, mesh, axes, lockstep=True,
-                                        **kw)
+    apply = _sharded_bank_apply(bank.plan, bank.src_window, bank.n_source,
+                                mesh, tuple(axes),
+                                spectral_mode=spectral_mode,
+                                backend=backend, pencil_axes=pencil_axes)
     k0 = bank.kernel_at_zero  # (S,); output scales are folded into the bank
 
     def matvec(x: Array) -> Array:
-        lockstep = x.ndim == 3
-        if lockstep:
-            xp = jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
-            y_sorted = _mv_lock(bank.multiplier_bank, base, w1d, xp[:, perm])
-        else:
-            batched = x.ndim == 2
-            xb = x if batched else x[:, None]
-            xp = jnp.pad(xb, ((0, pad), (0, 0))) if pad else xb
-            y_sorted = _mv_bcast(bank.multiplier_bank, base, w1d, xp[perm])
-        y = _unsort_rows(y_sorted, inv_perm, mesh, 1)
-        if pad:
-            y = y[:, :n]
-        if lockstep:
-            return y - k0[:, None, None] * x
-        if not batched:
-            return y[..., 0] - k0[:, None] * x
-        return y - k0[:, None, None] * x[None]
+        y = apply(bank.multiplier_bank, x)
+        return y - k0.reshape((-1,) + (1,) * (y.ndim - 1)) * x
 
     return matvec

@@ -103,24 +103,32 @@ def test_eigsh_poisoned_operator_flagged_not_trusted():
 
 
 def test_grid_hook_is_the_fault_seam():
-    """``fused_pipeline(grid_hook=...)``: identity hook changes nothing;
-    a poisoning hook propagates NaN to the output (which the serving
-    guard then catches)."""
+    """The spread grid's seam is ``fused_pipeline(spectral_op=...)``: an op
+    that runs the normal mid-section (rfftn -> multiply -> irfftn) changes
+    nothing, bit for bit; a poisoning op propagates NaN to every output
+    (which the serving guard then catches)."""
     rng = np.random.default_rng(8)
     pts = jnp.asarray(rng.normal(size=(100, 2)))
     fs = make_fastsum(make_kernel("gaussian", sigma=3.5), pts,
                       FastsumParams(n_bandwidth=16, m=4))
-    mult = fused_spectral_multiplier(fs.plan, fs.b_hat)
+    plan = fs.plan
+    mult = fused_spectral_multiplier(plan, fs.b_hat)
     x = jnp.asarray(rng.normal(size=(100,)))
-    base = fastsum_exec.fused_pipeline(fs.plan, mult, fs.src_window,
+    axes = tuple(range(plan.d))
+
+    def mid(g, bank):
+        g_hat = jnp.fft.rfftn(g, axes=axes) * bank[0][..., None]
+        return jnp.fft.irfftn(g_hat, s=(plan.grid_size,) * plan.d,
+                              axes=axes)
+
+    base = fastsum_exec.fused_pipeline(plan, mult, fs.src_window,
                                        fs.src_window, x)
-    same = fastsum_exec.fused_pipeline(fs.plan, mult, fs.src_window,
-                                       fs.src_window, x,
-                                       grid_hook=lambda g: g)
+    same = fastsum_exec.fused_pipeline(plan, mult, fs.src_window,
+                                       fs.src_window, x, spectral_op=mid)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(same))
     bad = fastsum_exec.fused_pipeline(
-        fs.plan, mult, fs.src_window, fs.src_window, x,
-        grid_hook=lambda g: jnp.full_like(g, jnp.nan))
+        plan, mult, fs.src_window, fs.src_window, x,
+        spectral_op=lambda g, bank: jnp.full_like(g, jnp.nan))
     assert not np.any(np.isfinite(np.asarray(bad)))
 
 
